@@ -489,10 +489,8 @@ class EulerSampler:
         Returns:
           (x_final, SamplerStats)
         """
-        # jit only from a clean trace state: args or model_fn captures may
-        # carry tracers from an outer jit/grad, where the inline scan is
-        # the correct (and equivalent) path.
-        if not self.jit or not jax.core.trace_state_clean():
+        # under an outer jit/grad trace, jax.jit inlines into that trace
+        if not self.jit:
             x = self._scan_loop(model_fn, rng, x_init)
         else:
             fn = self._jit_cache.get(model_fn)

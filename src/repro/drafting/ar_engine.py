@@ -167,8 +167,11 @@ class LSTMDraftAdapter:
 
     def init_cache(self, batch: int, max_len: int):
         cfg = self.model.cfg
-        z = jnp.zeros((cfg.num_layers, batch, cfg.hidden), jnp.float32)
-        return {"h": z, "c": z}
+        shape = (cfg.num_layers, batch, cfg.hidden)
+        # two buffers: the cache is donated, and XLA refuses one buffer
+        # donated twice
+        return {"h": jnp.zeros(shape, jnp.float32),
+                "c": jnp.zeros(shape, jnp.float32)}
 
     def _unstack(self, cache):
         n = self.model.cfg.num_layers

@@ -24,6 +24,7 @@ makes that operational:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, Optional, Sequence, Tuple
 
@@ -75,8 +76,10 @@ def make_quality_scorer(
         raise ValueError(
             f"probe times must lie in (0, 1), got {times}")
 
+    # the weights are an argument, not a closure: a closure would bake
+    # them into every compiled program as constants
     @jax.jit
-    def score(tokens: jax.Array) -> jax.Array:
+    def score(params, tokens: jax.Array) -> jax.Array:
         tokens = jnp.asarray(tokens, jnp.int32)
 
         def one_time(tp: float) -> jax.Array:
@@ -93,7 +96,7 @@ def make_quality_scorer(
             total = total + one_time(tp)
         return total / len(times)
 
-    return score
+    return functools.partial(score, params)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -6,26 +6,30 @@ scan-prefill token stream *bitwise*. Under plain XLA that fails — a
 (B, S, D) matmul/layernorm/softmax tiles its reductions differently at
 S=1 (decode) and S=P (prefill), drifting ~1e-6 in the logits and
 eventually flipping a sampled token. These kernels pin the reduction
-order by construction: every token is processed by its own grid program
-at the SAME block shapes regardless of how many tokens share the
-dispatch, so the only thing that changes between decode and prefill is
-the grid size — never the shape (and therefore never the reduction
-order) of any dot, norm or softmax.
+order by construction: every grid program takes a block of ROWS = 8
+token rows at the SAME block shapes regardless of how many tokens share
+the dispatch (the token rows are zero-padded to a multiple of ROWS), so
+the only thing that changes between decode and prefill is the grid
+size — never the shape (and therefore never the reduction order) of any
+dot, norm or softmax. Every reduction runs along one row, so a row's
+values do not depend on the other rows of its block. Eight rows is the
+smallest block a TPU kernel takes (one f32 sublane tile).
 
 Four kernels cover every reduction in the draft transformer forward:
 
-  ``_qkv_rope_kernel``   ln1 -> q/k/v projections -> RoPE, one token per
-                         program (grid over the flattened B*S tokens).
-  ``_attn_kernel``       one query token against the FULL (T = max_len)
-                         KV cache buffer — the cache length is static,
-                         so the softmax/PV reductions run over the same
-                         T lanes in decode and prefill; masking handles
-                         causality and cache validity.
+  ``_qkv_rope_kernel``   ln1 -> q/k/v projections -> RoPE (grid over
+                         blocks of the flattened B*S tokens).
+  ``_attn_kernel``       ROWS query tokens of one batch row against the
+                         FULL (T = max_len) KV cache buffer — the cache
+                         length is static, so the softmax/PV reductions
+                         run over the same T lanes in decode and prefill;
+                         masking handles causality and cache validity.
   ``_post_attn_kernel``  wo projection + residual + ln2 + MLP + residual.
   ``_head_kernel``       final norm + vocab projection.
 
 Everything *between* kernels is exact data movement (embedding gather,
-``dynamic_update_slice`` cache writes, reshapes) which cannot change
+``dynamic_update_slice`` cache writes, reshapes, transposes, padding)
+which cannot change
 values. See ops.py for the dispatcher and the supported-config gate.
 """
 
@@ -37,12 +41,16 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.3819763e38  # matches models/attention.py's mask constant
+# token rows per grid program: one f32 sublane tile, the smallest row
+# block a TPU kernel may take
+ROWS = 8
 
 
 def _norm_row(x, scale, bias, *, kind: str, eps: float):
-    """Row norm at fixed (1, D) shape; mirrors models/common.py formulas."""
+    """Row norm at fixed (ROWS, D) shape; mirrors models/common.py formulas."""
     xf = x.astype(jnp.float32)
     if kind == "layernorm":
         mu = jnp.mean(xf, axis=-1, keepdims=True)
@@ -69,31 +77,38 @@ def _dot(a, b):
         a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _rope_row(x, pos, *, heads: int, head_dim: int, theta: float):
-    """RoPE for one token: x (heads*head_dim,), pos scalar int32."""
+def _rope_rows(x, pos, freq, *, head_dim: int):
+    """RoPE on a block of token rows, lane-wise.
+
+    x (rows, heads*head_dim); pos (rows, 1) f32; freq (1, heads*head_dim)
+    holds each lane's frequency. Lane ``l`` of a head's first half pairs
+    with ``l + half`` and lane ``l`` of its second half with ``l - half``,
+    so ``x * cos + partner * sin`` is ``[x1 cos - x2 sin, x2 cos + x1 sin]``
+    with no reshape.
+    """
     half = head_dim // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32) * freq                     # (half,)
+    ang = pos * freq
     sin, cos = jnp.sin(ang), jnp.cos(ang)
-    xh = x.reshape(heads, head_dim)
-    x1, x2 = xh[:, :half], xh[:, half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.reshape(heads * head_dim)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    first = lane % head_dim < half
+    partner = jnp.where(first, -jnp.roll(x, -half, axis=1),
+                        jnp.roll(x, half, axis=1))
+    return x * cos + partner * sin
 
 
 def _qkv_rope_kernel(
-    x_ref,        # (1, D)
-    pos_ref,      # (1, 1) int32 — absolute position of this token
+    x_ref,        # (ROWS, D)
+    pos_ref,      # (ROWS, 1) int32 — absolute position of each token
     lns_ref,      # (1, D) ln1 scale
     lnb_ref,      # (1, D) ln1 bias (zeros for rmsnorm)
     wq_ref,       # (D, H*hd)
     wk_ref,       # (D, KH*hd)
     wv_ref,       # (D, KH*hd)
     bq_ref, bk_ref, bv_ref,   # (1, *) biases (zeros when use_bias=False)
-    q_ref, k_ref, v_ref,      # outputs (1, H*hd) / (1, KH*hd) / (1, KH*hd)
+    fq_ref, fk_ref,           # (1, H*hd) / (1, KH*hd) per-lane RoPE freqs
+    q_ref, k_ref, v_ref,      # outputs (ROWS, H*hd) / (ROWS, KH*hd) x2
     *,
-    norm: str, eps: float, use_bias: bool, use_rope: bool, theta: float,
-    heads: int, kv_heads: int, head_dim: int,
+    norm: str, eps: float, use_bias: bool, use_rope: bool, head_dim: int,
 ):
     h = _norm_row(x_ref[...], lns_ref[...], lnb_ref[...], kind=norm, eps=eps)
     q = _dot(h, wq_ref[...].astype(jnp.float32))
@@ -104,60 +119,53 @@ def _qkv_rope_kernel(
         k = k + bk_ref[...].astype(jnp.float32)
         v = v + bv_ref[...].astype(jnp.float32)
     if use_rope:
-        pos = pos_ref[0, 0]
-        q = _rope_row(q[0], pos, heads=heads, head_dim=head_dim,
-                      theta=theta)[None]
-        k = _rope_row(k[0], pos, heads=kv_heads, head_dim=head_dim,
-                      theta=theta)[None]
+        pos = pos_ref[...].astype(jnp.float32)
+        q = _rope_rows(q, pos, fq_ref[...], head_dim=head_dim)
+        k = _rope_rows(k, pos, fk_ref[...], head_dim=head_dim)
     q_ref[...] = q
     k_ref[...] = k
     v_ref[...] = v
 
 
 def _attn_kernel(
-    q_ref,        # (1, 1, H*hd) — this token's query
-    k_ref,        # (1, T, KH*hd) — the row's FULL cache buffer
-    v_ref,        # (1, T, KH*hd)
-    pos_ref,      # (1, 1) int32 — this token's absolute position
-    end_ref,      # (1, 1) int32 — cache validity end (start + s)
-    out_ref,      # (1, 1, H*hd)
+    q_ref,        # (1, H, ROWS, hd) — ROWS query tokens of one batch row
+    k_ref,        # (1, KH, T, hd) — the row's FULL cache buffer
+    v_ref,        # (1, KH, T, hd)
+    sc_ref,       # SMEM (2,) int32 — [position of token 0, cache end]
+    out_ref,      # (1, H, ROWS, hd)
     *,
     heads: int, kv_heads: int, head_dim: int,
 ):
     g = heads // kv_heads
-    t = k_ref.shape[1]
+    rows, t = q_ref.shape[2], k_ref.shape[2]
     scale = 1.0 / math.sqrt(head_dim)
-    pos = pos_ref[0, 0]
-    end = end_ref[0, 0]
+    pos = (sc_ref[0] + pl.program_id(1) * rows
+           + jax.lax.broadcasted_iota(jnp.int32, (rows, t), 0))
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, t), 1)
+    valid = (col <= pos) & (col < sc_ref[1])
 
-    qh = q_ref[0, 0].astype(jnp.float32).reshape(kv_heads, g, head_dim)
-    kh = k_ref[0].astype(jnp.float32).reshape(t, kv_heads, head_dim)
-    vh = v_ref[0].astype(jnp.float32).reshape(t, kv_heads, head_dim)
-
-    col = jax.lax.broadcasted_iota(jnp.int32, (g, t), 1)
-    valid = (col <= pos) & (col < end)
-
-    outs = []
-    for i in range(kv_heads):
-        sc = _dot(qh[i], kh[:, i, :].T) * scale            # (G, T)
+    for h in range(heads):
+        q = q_ref[0, h].astype(jnp.float32)                # (ROWS, hd)
+        k = k_ref[0, h // g].astype(jnp.float32)           # (T, hd)
+        sc = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # (ROWS, T)
         sc = jnp.where(valid, sc, NEG_INF)
         m = jnp.max(sc, axis=-1, keepdims=True)
         p = jnp.exp(sc - m)
         l = jnp.sum(p, axis=-1, keepdims=True)
-        outs.append(_dot(p, vh[:, i, :]) / l)              # (G, hd)
-    out = jnp.stack(outs, axis=0)                          # (KH, G, hd)
-    out_ref[...] = out.reshape(1, 1, heads * head_dim)
+        out_ref[0, h] = _dot(p, v_ref[0, h // g].astype(jnp.float32)) / l
 
 
 def _post_attn_kernel(
-    a_ref,        # (1, H*hd) — attention output for this token
-    x_ref,        # (1, D) — residual stream input
+    a_ref,        # (ROWS, H*hd) — attention output of each token
+    x_ref,        # (ROWS, D) — residual stream input
     wo_ref, bo_ref,           # (H*hd, D), (1, D)
     lns_ref, lnb_ref,         # ln2 scale/bias
     wup_ref, bup_ref,         # (D, F), (1, F)
     wgate_ref, bgate_ref,     # (D, F), (1, F) (zeros when ungated)
     wdown_ref, bdown_ref,     # (F, D), (1, D)
-    out_ref,      # (1, D)
+    out_ref,      # (ROWS, D)
     *,
     norm: str, eps: float, use_bias: bool, act: str, gated: bool,
 ):
@@ -183,11 +191,11 @@ def _post_attn_kernel(
 
 
 def _head_kernel(
-    x_ref,        # (1, D)
+    x_ref,        # (ROWS, D)
     lns_ref, lnb_ref,         # final norm scale/bias
     w_ref,        # (D, V) — the head matrix (embed table pre-transposed
                   #          host-side when tie_embeddings)
-    out_ref,      # (1, V)
+    out_ref,      # (ROWS, V)
     *,
     norm: str, eps: float,
 ):
@@ -196,20 +204,35 @@ def _head_kernel(
 
 
 # ---------------------------------------------------------------------------
-# pallas_call wrappers (grid over tokens; weights are whole-array blocks)
+# pallas_call wrappers (grid over ROWS-token blocks; weights are whole-array
+# blocks). Callers pad the token rows to a multiple of ROWS.
 # ---------------------------------------------------------------------------
 
-def _row_spec():
-    return pl.BlockSpec((1, 1), lambda i: (i, 0))
+def _rows_spec(width: int):
+    return pl.BlockSpec((ROWS, width), lambda i: (i, 0))
 
 
 def _full2(a):
     return pl.BlockSpec(a.shape, lambda i: (0, 0))
 
 
+def pad_rows(a, rows: int):
+    """Zero-pad the leading axis of ``a`` to ``rows``."""
+    extra = rows - a.shape[0]
+    return a if extra == 0 else jnp.pad(a, ((0, extra),) + ((0, 0),) * (a.ndim - 1))
+
+
+def rope_lane_freqs(heads: int, head_dim: int, theta: float):
+    """(1, heads*head_dim) frequency of each lane, as models/rope.py."""
+    half = head_dim // 2
+    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    return jnp.tile(freq, 2 * heads).reshape(1, heads * head_dim)
+
+
 def qkv_rope_pallas(x, pos_r, ln, attn_p, *, norm, eps, use_bias, use_rope,
                     theta, heads, kv_heads, head_dim, interpret):
-    """x (R, D); pos_r (R, 1) int32 -> (q (R, H*hd), k, v (R, KH*hd))."""
+    """x (R, D); pos_r (R, 1) int32; R % ROWS == 0 ->
+    (q (R, H*hd), k, v (R, KH*hd))."""
     r, d = x.shape
     qd, kd = heads * head_dim, kv_heads * head_dim
     lns = ln["scale"].reshape(1, d)
@@ -219,66 +242,61 @@ def qkv_rope_pallas(x, pos_r, ln, attn_p, *, norm, eps, use_bias, use_rope,
     bq = attn_p["wq"].get("b", zq[0]).reshape(1, qd)
     bk = attn_p["wk"].get("b", zk[0]).reshape(1, kd)
     bv = attn_p["wv"].get("b", zk[0]).reshape(1, kd)
+    fq = rope_lane_freqs(heads, head_dim, theta)
+    fk = rope_lane_freqs(kv_heads, head_dim, theta)
     kernel = functools.partial(
         _qkv_rope_kernel, norm=norm, eps=eps, use_bias=use_bias,
-        use_rope=use_rope, theta=theta, heads=heads, kv_heads=kv_heads,
-        head_dim=head_dim)
+        use_rope=use_rope, head_dim=head_dim)
     args = (x, pos_r, lns, lnb, attn_p["wq"]["w"], attn_p["wk"]["w"],
-            attn_p["wv"]["w"], bq, bk, bv)
-    in_specs = [
-        pl.BlockSpec((1, d), lambda i: (i, 0)),
-        _row_spec(), _full2(lns), _full2(lnb),
-        _full2(attn_p["wq"]["w"]), _full2(attn_p["wk"]["w"]),
-        _full2(attn_p["wv"]["w"]), _full2(bq), _full2(bk), _full2(bv),
-    ]
-    out_specs = (
-        pl.BlockSpec((1, qd), lambda i: (i, 0)),
-        pl.BlockSpec((1, kd), lambda i: (i, 0)),
-        pl.BlockSpec((1, kd), lambda i: (i, 0)),
-    )
+            attn_p["wv"]["w"], bq, bk, bv, fq, fk)
+    in_specs = [_rows_spec(d), _rows_spec(1)] + [_full2(a) for a in args[2:]]
+    out_specs = (_rows_spec(qd), _rows_spec(kd), _rows_spec(kd))
     out_shape = (
         jax.ShapeDtypeStruct((r, qd), jnp.float32),
         jax.ShapeDtypeStruct((r, kd), jnp.float32),
         jax.ShapeDtypeStruct((r, kd), jnp.float32),
     )
-    return pl.pallas_call(kernel, grid=(r,), in_specs=in_specs,
+    return pl.pallas_call(kernel, grid=(r // ROWS,), in_specs=in_specs,
                           out_specs=out_specs, out_shape=out_shape,
                           interpret=interpret)(*args)
 
 
-def attn_cached_pallas(q, kbuf, vbuf, q_pos, end, *, seq: int, heads,
-                       kv_heads, head_dim, interpret):
-    """q (B, S, H*hd); kbuf/vbuf (B, T, KH*hd); q_pos (R, 1); end (1, 1).
+def attn_cached_pallas(q, kbuf, vbuf, pos0, end, *, heads, kv_heads,
+                       head_dim, interpret):
+    """q (B, S, H*hd); kbuf/vbuf (B, T, KH*hd); pos0 / end int32 scalars.
 
-    One grid program per query token; each reads its batch row's full
-    T-length cache, so the reduction order over keys is identical for
-    decode (S=1) and batched prefill (S=P).
+    Each grid program takes ROWS query tokens of one batch row against
+    that row's full T-length cache, so the reduction order over keys is
+    the same for decode (S=1) and batched prefill (S=P). The head-major
+    transposes around the call are data movement.
     """
-    b, s, qd = q.shape
+    b, s, _ = q.shape
     t = kbuf.shape[1]
-    kd = kv_heads * head_dim
-    r = b * s
-    qf = q.reshape(r, 1, qd)
+    sp = -(-s // ROWS) * ROWS
+    qh = q.reshape(b, s, heads, head_dim).transpose(0, 2, 1, 3)
+    qh = jnp.pad(qh, ((0, 0), (0, 0), (0, sp - s), (0, 0)))
+    kh = kbuf.reshape(b, t, kv_heads, head_dim).transpose(0, 2, 1, 3)
+    vh = vbuf.reshape(b, t, kv_heads, head_dim).transpose(0, 2, 1, 3)
+    sc = jnp.stack([jnp.asarray(pos0, jnp.int32), jnp.asarray(end, jnp.int32)])
     kernel = functools.partial(_attn_kernel, heads=heads, kv_heads=kv_heads,
                                head_dim=head_dim)
-    in_specs = [
-        pl.BlockSpec((1, 1, qd), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, t, kd), lambda i: (i // seq, 0, 0)),
-        pl.BlockSpec((1, t, kd), lambda i: (i // seq, 0, 0)),
-        _row_spec(),
-        pl.BlockSpec((1, 1), lambda i: (0, 0)),
-    ]
+    q_spec = pl.BlockSpec((1, heads, ROWS, head_dim), lambda i, j: (i, 0, j, 0))
+    kv_spec = pl.BlockSpec((1, kv_heads, t, head_dim),
+                           lambda i, j: (i, 0, 0, 0))
     out = pl.pallas_call(
-        kernel, grid=(r,), in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, qd), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, 1, qd), jnp.float32),
-        interpret=interpret)(qf, kbuf, vbuf, q_pos, end)
-    return out.reshape(b, s, qd)
+        kernel, grid=(b, sp // ROWS),
+        in_specs=[q_spec, kv_spec, kv_spec,
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, heads, sp, head_dim), jnp.float32),
+        interpret=interpret)(qh, kh, vh, sc)
+    return out[:, :, :s].transpose(0, 2, 1, 3).reshape(b, s, heads * head_dim)
 
 
 def post_attn_pallas(a, x, attn_p, ln, mlp_p, *, norm, eps, use_bias, act,
                      interpret):
-    """a (R, H*hd) attention out; x (R, D) residual -> (R, D)."""
+    """a (R, H*hd) attention out; x (R, D) residual; R % ROWS == 0 ->
+    (R, D)."""
     r, d = x.shape
     qd = a.shape[1]
     f = mlp_p["up"]["w"].shape[1]
@@ -297,34 +315,25 @@ def post_attn_pallas(a, x, attn_p, ln, mlp_p, *, norm, eps, use_bias, act,
                                use_bias=use_bias, act=act, gated=gated)
     args = (a, x, attn_p["wo"]["w"], bo, lns, lnb, mlp_p["up"]["w"], bup,
             wgate, bgate, mlp_p["down"]["w"], bdown)
-    in_specs = [
-        pl.BlockSpec((1, qd), lambda i: (i, 0)),
-        pl.BlockSpec((1, d), lambda i: (i, 0)),
-        _full2(attn_p["wo"]["w"]), _full2(bo), _full2(lns), _full2(lnb),
-        _full2(mlp_p["up"]["w"]), _full2(bup), _full2(wgate), _full2(bgate),
-        _full2(mlp_p["down"]["w"]), _full2(bdown),
-    ]
+    in_specs = [_rows_spec(qd), _rows_spec(d)] + [_full2(w) for w in args[2:]]
     return pl.pallas_call(
-        kernel, grid=(r,), in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, d), lambda i: (i, 0)),
+        kernel, grid=(r // ROWS,), in_specs=in_specs,
+        out_specs=_rows_spec(d),
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.float32),
         interpret=interpret)(*args)
 
 
 def head_pallas(x, fn, w, *, norm, eps, interpret):
-    """x (R, D); w (D, V) -> logits (R, V)."""
+    """x (R, D); w (D, V); R % ROWS == 0 -> logits (R, V)."""
     r, d = x.shape
     v = w.shape[1]
     lns = fn["scale"].reshape(1, d)
     lnb = (fn["bias"] if "bias" in fn else jnp.zeros_like(fn["scale"])
            ).reshape(1, d)
     kernel = functools.partial(_head_kernel, norm=norm, eps=eps)
-    in_specs = [
-        pl.BlockSpec((1, d), lambda i: (i, 0)),
-        _full2(lns), _full2(lnb), _full2(w),
-    ]
     return pl.pallas_call(
-        kernel, grid=(r,), in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, v), lambda i: (i, 0)),
+        kernel, grid=(r // ROWS,),
+        in_specs=[_rows_spec(d), _full2(lns), _full2(lnb), _full2(w)],
+        out_specs=_rows_spec(v),
         out_shape=jax.ShapeDtypeStruct((r, v), jnp.float32),
         interpret=interpret)(x, lns, lnb, w)

@@ -2,9 +2,9 @@
 
 ``DraftDecoder(model).forward_chunk(params, toks (B, S), cache, pos)``
 replaces ``Model.decode_step`` (S=1) AND ``Model.prefill`` (S=P) with one
-shared code path built from the per-token Pallas kernels in kernel.py.
-Because both call sites run the SAME kernels at the SAME block shapes —
-only the token-grid size differs — a multi-token batched prefill is
+shared code path built from the token-row-block Pallas kernels in
+kernel.py. Because both call sites run the SAME kernels at the SAME block
+shapes — only the token-grid size differs — a multi-token batched prefill is
 bit-identical to scanning the tokens one at a time, which is what lets
 ``drafting/ar_engine.py`` flip ``prefill_mode="batched"`` to default
 without giving up its oracle bit-exactness contract.
@@ -27,7 +27,8 @@ import jax.numpy as jnp
 
 from repro.kernels import resolve_interpret
 from repro.kernels.draft_decode.kernel import (
-    attn_cached_pallas, head_pallas, post_attn_pallas, qkv_rope_pallas,
+    ROWS, attn_cached_pallas, head_pallas, pad_rows, post_attn_pallas,
+    qkv_rope_pallas,
 )
 
 
@@ -76,28 +77,30 @@ class DraftDecoder:
 
     # -- one transformer layer over the flattened token rows ---------------
 
-    def _layer(self, lp, x2, kbuf, vbuf, start, pos_r, b, s, interpret):
+    def _layer(self, lp, x2, kbuf, vbuf, start, pos0, pos_r, b, s,
+               interpret):
+        """x2 / pos_r carry the B*S token rows padded to a multiple of
+        ROWS; kbuf / vbuf are (B, T, KH*hd)."""
         cfg = self.model.cfg
         kh, hd = cfg.num_kv_heads, cfg.head_dim
+        r, rp = b * s, x2.shape[0]
         q, k, v = qkv_rope_pallas(
             x2, pos_r, lp["ln1"], lp["attn"],
             norm=cfg.norm, eps=cfg.norm_eps, use_bias=cfg.use_bias,
             use_rope=cfg.rope_type == "default", theta=cfg.rope_theta,
             heads=cfg.num_heads, kv_heads=kh, head_dim=hd,
             interpret=interpret)
-        k4 = k.reshape(b, s, kh * hd)
-        v4 = v.reshape(b, s, kh * hd)
-        t = kbuf.shape[1]
-        kbuf = jax.lax.dynamic_update_slice(kbuf, k4, (0, start, 0))
-        vbuf = jax.lax.dynamic_update_slice(vbuf, v4, (0, start, 0))
-        end = (start + s).astype(jnp.int32).reshape(1, 1)
+        kbuf = jax.lax.dynamic_update_slice(
+            kbuf, k[:r].reshape(b, s, kh * hd), (0, start, 0))
+        vbuf = jax.lax.dynamic_update_slice(
+            vbuf, v[:r].reshape(b, s, kh * hd), (0, start, 0))
         a = attn_cached_pallas(
-            q.reshape(b, s, cfg.num_heads * hd), kbuf, vbuf, pos_r, end,
-            seq=s, heads=cfg.num_heads, kv_heads=kh, head_dim=hd,
+            q[:r].reshape(b, s, cfg.num_heads * hd), kbuf, vbuf, pos0,
+            start + s, heads=cfg.num_heads, kv_heads=kh, head_dim=hd,
             interpret=interpret)
         x2 = post_attn_pallas(
-            a.reshape(b * s, cfg.num_heads * hd), x2, lp["attn"], lp["ln2"],
-            lp["mlp"], norm=cfg.norm, eps=cfg.norm_eps,
+            pad_rows(a.reshape(r, cfg.num_heads * hd), rp), x2, lp["attn"],
+            lp["ln2"], lp["mlp"], norm=cfg.norm, eps=cfg.norm_eps,
             use_bias=cfg.use_bias, act=cfg.act, interpret=interpret)
         return x2, kbuf, vbuf
 
@@ -117,12 +120,13 @@ class DraftDecoder:
         kh, hd = cfg.num_kv_heads, cfg.head_dim
         reps, rem = cfg.scan_split()
 
+        rp = -(-(b * s) // ROWS) * ROWS
         table = params["embed"]["table"].astype(jnp.float32)
-        x2 = jnp.take(table, toks, axis=0).reshape(b * s, d)
+        x2 = pad_rows(jnp.take(table, toks, axis=0).reshape(b * s, d), rp)
         pos0 = jnp.asarray(pos, jnp.int32)
-        pos_r = jnp.broadcast_to(
+        pos_r = pad_rows(jnp.broadcast_to(
             pos0 + jnp.arange(s, dtype=jnp.int32)[None, :], (b, s)
-        ).reshape(b * s, 1)
+        ).reshape(b * s, 1), rp)
 
         new_cache: dict = {"blocks": {}, "rem": {}, "pre": {}}
 
@@ -138,8 +142,8 @@ class DraftDecoder:
                 start = bc["pos"][i].astype(jnp.int32)
                 kb = kbufs[i].reshape(b, t, kh * hd)
                 vb = vbufs[i].reshape(b, t, kh * hd)
-                x2, kb, vb = self._layer(lp, x2, kb, vb, start, pos_r, b, s,
-                                         interpret)
+                x2, kb, vb = self._layer(lp, x2, kb, vb, start, pos0, pos_r,
+                                         b, s, interpret)
                 kbufs = kbufs.at[i].set(kb.reshape(b, t, kh, hd))
                 vbufs = vbufs.at[i].set(vb.reshape(b, t, kh, hd))
             new_cache["blocks"]["p0"] = {
@@ -154,8 +158,8 @@ class DraftDecoder:
             start = rc["pos"].astype(jnp.int32)
             kb = rc["k"].reshape(b, t, kh * hd)
             vb = rc["v"].reshape(b, t, kh * hd)
-            x2, kb, vb = self._layer(lp, x2, kb, vb, start, pos_r, b, s,
-                                     interpret)
+            x2, kb, vb = self._layer(lp, x2, kb, vb, start, pos0, pos_r,
+                                     b, s, interpret)
             new_cache["rem"][f"r{j}"] = {
                 "k": kb.reshape(b, t, kh, hd), "v": vb.reshape(b, t, kh, hd),
                 "pos": rc["pos"] + jnp.asarray(s, rc["pos"].dtype),
@@ -167,4 +171,4 @@ class DraftDecoder:
             w = params["head"]["w"].astype(jnp.float32)
         logits = head_pallas(x2, params["final_norm"], w, norm=cfg.norm,
                              eps=cfg.norm_eps, interpret=interpret)
-        return logits.reshape(b, s, cfg.vocab_size), new_cache
+        return logits[:b * s].reshape(b, s, cfg.vocab_size), new_cache

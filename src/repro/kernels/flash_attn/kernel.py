@@ -135,12 +135,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(q, k, v)
-
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_compiler_params = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))

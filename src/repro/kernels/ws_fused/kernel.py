@@ -38,7 +38,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ws_step.kernel import (
-    MIN_PROB, NEG, gumbel_from_bits, threefry2x32,
+    MIN_PROB, NEG, gumbel_from_bits, hw_prng_bits, threefry2x32,
 )
 
 
@@ -83,10 +83,8 @@ def _ws_fused_kernel(
 
     # -- in-kernel Gumbel noise (same two paths as ws_step) ----------------
     if use_hw_prng:
-        pltpu.prng_seed(seed_ref[j, 0], seed_ref[j, 1], i, k)
-        bits = pltpu.prng_random_bits((br, bv))
-        if bits.dtype != jnp.uint32:
-            bits = pltpu.bitcast(bits, jnp.uint32)
+        # step j has its own key words; (row block, vocab tile) fold in
+        bits = hw_prng_bits(seed_ref[j, 0], seed_ref[j, 1], i, k, (br, bv))
     else:
         sl = seed_ref[pl.ds(j, 1)]                  # (1, BR, 2)
         k0 = sl[0, :, 0:1].astype(jnp.uint32)       # (BR, 1) per-row key

@@ -99,9 +99,29 @@ def threefry2x32(k0, k1, c0, c1):
 
 
 def gumbel_from_bits(bits: jax.Array) -> jax.Array:
-    """uint32 bits -> standard Gumbel(0, 1) float32, u strictly in (0, 1)."""
-    u = ((bits >> 8).astype(jnp.float32) + 0.5) * (1.0 / (1 << 24))
+    """uint32 bits -> standard Gumbel(0, 1) float32, u strictly in (0, 1).
+
+    The top 24 bits go through int32 (Mosaic has no uint32 -> float32
+    cast); below 2**24 that is the same value.
+    """
+    u = ((bits >> 8).astype(jnp.int32).astype(jnp.float32) + 0.5) * (
+        1.0 / (1 << 24))
     return -jnp.log(-jnp.log(u))
+
+
+# 0x9E3779B9 as int32: odd, so ``n -> n * _GOLDEN`` is a bijection mod 2**32
+_GOLDEN = -1640531527
+
+
+def hw_prng_bits(seed0, seed1, block, tile, shape) -> jax.Array:
+    """uint32 bits from the TPU hardware PRNG for one grid program.
+
+    Mosaic takes at most two seed words, so the grid coordinates are
+    folded into them: distinct ``(block, tile)`` pairs give distinct
+    seeds under one key.
+    """
+    pltpu.prng_seed(seed0 ^ (block * _GOLDEN), seed1 ^ (tile * _GOLDEN))
+    return pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
 
 
 def threefry_gumbel(seed: jax.Array, rows: int, cols: int) -> jax.Array:
@@ -157,10 +177,7 @@ def _ws_step_streamed_kernel(
 
     # -- in-kernel Gumbel noise: no (R, V) HBM tensor ----------------------
     if use_hw_prng:
-        pltpu.prng_seed(seed_ref[0], seed_ref[1], i, j)
-        bits = pltpu.prng_random_bits((br, bv))
-        if bits.dtype != jnp.uint32:
-            bits = pltpu.bitcast(bits, jnp.uint32)
+        bits = hw_prng_bits(seed_ref[0], seed_ref[1], i, j, (br, bv))
     else:
         rows = i * br + jax.lax.broadcasted_iota(jnp.int32, (br, bv), 0)
         bits, _ = threefry2x32(

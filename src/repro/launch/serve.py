@@ -93,6 +93,7 @@ from repro.configs.base import RunConfig
 from repro.configs.dfm_dit import tiny_config
 from repro.core import CorruptionDraft, KNNRefinementCoupling, WarmStartPath, pair_iterator
 from repro.data import SyntheticCorpus, TEXT_VOCAB, decode
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import LSTMConfig, LSTMModel, build_model
 from repro.optim import AdamW
 from repro.serving import WarmStartScheduler, WarmStartServer, batch_keyed_draft
@@ -197,6 +198,7 @@ def main():
                          "every this many seconds while serving "
                          "(0 = off; streaming mode)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if (args.trace_out or args.metrics_out) and not args.scheduler:
         print("--trace-out/--metrics-out imply --scheduler; enabling it")

@@ -87,6 +87,21 @@ def test_lstm_engine_matches_oracle(lstm):
     assert eng.stats.prefill_reuses == 1
 
 
+@pytest.mark.parametrize("adapter_fixture", ["lstm", "tfm"])
+def test_engine_with_donated_cache_matches_no_donation(
+        request, monkeypatch, adapter_fixture):
+    """Off the CPU the engine donates its cache buffers; every leaf must
+    be its own buffer, and the drafts must not change."""
+    adapter, params = request.getfixturevalue(adapter_fixture)
+    keys = keys_for(3)
+    ref = ARDraftEngine(adapter, params, max_len=16).generate_rows(keys, 6)
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    eng = ARDraftEngine(adapter, params, max_len=16)
+    for _ in range(2):                   # prefill, then the pooled prefix
+        np.testing.assert_array_equal(
+            np.asarray(eng.generate_rows(keys, 6)), np.asarray(ref))
+
+
 @pytest.mark.slow
 def test_partial_cache_reuse_is_bit_exact(tfm):
     """Prefix KV survives across calls (and across bucket switches); the

@@ -170,6 +170,17 @@ def test_param_specs_on_smoke_model():
         specs2 = param_specs(params_abs, TRAIN_RULES, mesh2)
 
 
+def test_local_mesh_axes_are_auto():
+    """The shard rules rely on propagated constraints: a mesh with
+    ``Explicit`` axes (``jax.make_mesh``'s default) rejects the model's
+    unannotated matmuls over a sharded contraction."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_local_mesh
+    mesh = make_local_mesh()
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+
+
 def test_logical_to_spec_drops_missing_axes():
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import TRAIN_RULES, logical_to_spec
