@@ -1,0 +1,9 @@
+"""95th percentile of the time to result over every request due in the
+window, drained after it closes: from the due time to the client's
+receipt."""
+
+from bench import readings
+
+
+def read(run):
+    return readings.ttr_ms(run, 95)
