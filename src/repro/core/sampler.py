@@ -231,6 +231,11 @@ def scan_refine_loop_rows(
     ``active`` is False; the backbone still evaluates the full batch each
     step — heterogeneity inside a micro-batch should therefore stay small
     (the batcher's t0-bins bound it).
+
+    The body's operations carry the ``named_scope`` ``backbone``
+    (``logits_fn``) or ``sample_step`` (step keys, ``one_step`` /
+    ``fused_fn`` and the row freeze) in their op metadata, which a
+    profile of the compiled program keeps.
     """
     if fused_block > 1:
         if fused_fn is None:
@@ -246,21 +251,27 @@ def scan_refine_loop_rows(
 
         def fused_body(x, inp):
             bt, bh, bi = inp                              # (K, B) each
-            keys = jax.vmap(
-                lambda idx: jax.vmap(jax.random.fold_in)(flow_keys, idx)
-            )(bi)                                         # (K, B) typed keys
-            logits = logits_fn(x, bt[0])
-            return fused_fn(keys, logits, x, bt, bh), None
+            with jax.named_scope("sample_step"):
+                keys = jax.vmap(
+                    lambda idx: jax.vmap(jax.random.fold_in)(flow_keys, idx)
+                )(bi)                                     # (K, B) typed keys
+            with jax.named_scope("backbone"):
+                logits = logits_fn(x, bt[0])
+            with jax.named_scope("sample_step"):
+                return fused_fn(keys, logits, x, bt, bh), None
 
         x, _ = jax.lax.scan(fused_body, x_init, (bts, bhs, bidx))
         return x
 
     def body(x, inp):
         t, h, act, idx = inp
-        keys = jax.vmap(jax.random.fold_in)(flow_keys, idx)
-        logits = logits_fn(x, t)
-        x_next = one_step(keys, logits, x, t, h)
-        return jnp.where(act[:, None], x_next, x), None
+        with jax.named_scope("sample_step"):
+            keys = jax.vmap(jax.random.fold_in)(flow_keys, idx)
+        with jax.named_scope("backbone"):
+            logits = logits_fn(x, t)
+        with jax.named_scope("sample_step"):
+            x_next = one_step(keys, logits, x, t, h)
+            return jnp.where(act[:, None], x_next, x), None
 
     x, _ = jax.lax.scan(body, x_init, (ts, hs, active, key_idx))
     return x

@@ -16,7 +16,7 @@ from __future__ import annotations
 import json
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 __all__ = [
     "Counter",
@@ -206,19 +206,6 @@ class MetricsRegistry:
         return total
 
     # -- dumps -------------------------------------------------------------
-
-    def render_text(self) -> str:
-        snap = self.snapshot()
-        lines: List[str] = []
-        for key in sorted(snap["counters"]):
-            lines.append(f"{key} {snap['counters'][key]}")
-        for key in sorted(snap["gauges"]):
-            lines.append(f"{key} {snap['gauges'][key]:.6g}")
-        for key in sorted(snap["histograms"]):
-            h = snap["histograms"][key]
-            mean = h["sum"] / h["count"] if h["count"] else 0.0
-            lines.append(f"{key} count={h['count']} sum={h['sum']:.6g} mean={mean:.6g}")
-        return "\n".join(lines)
 
     def dump_json(self, path: str) -> None:
         with open(path, "w") as f:

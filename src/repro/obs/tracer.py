@@ -6,8 +6,14 @@ and ``dropped`` is incremented, so a long serve never grows memory
 unboundedly. The default everywhere is :class:`NullTracer`, whose methods
 are no-ops, so instrumented hot paths pay ~zero when tracing is off.
 
+``SpanTracer(profiler=True)`` also puts every span on the profiler's
+host plane (``jax.profiler.TraceAnnotation``), on the clock of the
+device plane, so a ``jax.profiler`` trace shows each span beside the
+device work it caused. Instants and flow events stay in the ring only.
+
 This module is stdlib-only on purpose: ``repro.obs`` must be importable
-without jax/numpy so ``tools/trace_summary.py`` stays cheap.
+without jax/numpy so ``tools/trace_summary.py`` stays cheap; the
+profiler option imports jax when it is turned on.
 """
 
 from __future__ import annotations
@@ -43,14 +49,25 @@ class SpanRecord:
 
 
 class SpanTracer:
-    """Thread-safe bounded-ring span recorder."""
+    """Thread-safe bounded-ring span recorder.
+
+    ``profiler=True`` adds a second sink: each span also holds a
+    ``jax.profiler.TraceAnnotation`` open for its lifetime, named by the
+    span's ``profile`` argument (else its ``name``). The annotation costs
+    next to nothing while no profiler session is running.
+    """
 
     enabled = True
 
-    def __init__(self, capacity: int = 8192):
+    def __init__(self, capacity: int = 8192, *, profiler: bool = False):
         if capacity <= 0:
             raise ValueError(f"capacity must be positive, got {capacity}")
         self.capacity = int(capacity)
+        self._annotation = None
+        if profiler:
+            import jax  # only this sink needs jax
+
+            self._annotation = jax.profiler.TraceAnnotation
         self._lock = threading.Lock()
         self._ring: List[Optional[SpanRecord]] = [None] * self.capacity
         self._head = 0  # next write slot
@@ -98,6 +115,8 @@ class SpanTracer:
         track: str = "main",
         flow_id: Optional[int] = None,
         flow_ph: Optional[str] = None,
+        *,
+        profile: Optional[str] = None,
         **args: Any,
     ) -> Iterator[Dict[str, Any]]:
         """Context manager recording a complete ``"X"`` span on exit.
@@ -105,10 +124,16 @@ class SpanTracer:
         Yields the mutable ``args`` dict so callers can attach results
         discovered mid-span (e.g. jit-cache hit/miss, rows packed).
         Nestable: inner spans simply record their own (shorter) windows.
+        ``profile`` names the span on the profiler's host plane (the
+        ``profiler`` sink); it defaults to ``name``.
         """
         start = time.perf_counter()
+        annotation = (self._annotation(profile or name)
+                      if self._annotation is not None
+                      else contextlib.nullcontext())
         try:
-            yield args
+            with annotation:
+                yield args
         finally:
             self._append(
                 SpanRecord(

@@ -77,6 +77,13 @@ def _key_from_label(label: str) -> str:
         return f"({', '.join(parts)})"
     return label
 
+
+def _profile_name(stage: str, k: Optional[int] = None) -> str:
+    """A serving span's name on the profiler's host plane:
+    ``serve.<stage>``, and ``#<k>`` where micro-batch ``k`` is known (the
+    id its requests carry as ``CompletedRequest.micro_batch``)."""
+    return f"serve.{stage}" if k is None else f"serve.{stage}#{k}"
+
 # per-class SLO scaling for the streaming admission loop: a class's
 # deadline is arrival + slo * factor; None disarms the deadline entirely
 # (the class flushes only on full / idle / drain and is excluded from SLO
@@ -476,7 +483,9 @@ class WarmStartScheduler:
       tracer: optional :class:`repro.obs.SpanTracer` recording pipeline
         spans (draft worker, refine dispatch, scoring pre-pass, flush
         decisions) and per-request admission→terminal flow events for
-        Perfetto export. Defaults to the no-op
+        Perfetto export; with ``SpanTracer(profiler=True)`` the spans
+        also land on the profiler's host plane as ``serve.<stage>#<k>``
+        (see ``docs/ARCHITECTURE.md``). Defaults to the no-op
         :class:`repro.obs.NullTracer` — hot paths pay ~zero when off.
       metrics: optional :class:`repro.obs.MetricsRegistry`; the
         scheduler owns its serving counters there (terminal statuses,
@@ -792,9 +801,11 @@ class WarmStartScheduler:
         return seeds, idx
 
     def _stage_keys_and_draft(self, mb: MicroBatch,
-                              predrafted: Optional[Dict[int, np.ndarray]] = None):
-        """Draft stage for one micro-batch (runs on the worker thread):
-        derive per-row keys, generate drafts at bucket length, block.
+                              predrafted: Optional[Dict[int, np.ndarray]] = None,
+                              k: Optional[int] = None):
+        """Draft stage for one micro-batch ``k`` (runs on the worker
+        thread): derive per-row keys, generate drafts at bucket length,
+        block.
 
         ``predrafted`` (adaptive-t0 mode) maps request_id -> that
         request's (num_samples, bucket_len) drafts from the scoring
@@ -803,7 +814,9 @@ class WarmStartScheduler:
         way — padding rows just stay zero).
         """
         with self.tracer.span("draft", track="draft_worker",
-                              bucket=mb.bucket_len, rows=mb.rows,
+                              profile=_profile_name("draft", k),
+                              micro_batch=k, bucket=mb.bucket_len,
+                              rows=mb.rows,
                               predrafted=predrafted is not None):
             t0 = time.perf_counter()
             seeds, idx = self._mb_row_streams(mb)
@@ -826,9 +839,10 @@ class WarmStartScheduler:
         return x, flow_keys, t_draft
 
     def _dispatch_refine(self, mb: MicroBatch, x, flow_keys, ts, hs,
-                         active, key_idx):
+                         active, key_idx, k: Optional[int] = None):
         """The jit-cache dispatch wrapper: one refine-loop dispatch with
-        bounded-backoff retries (:class:`DispatchRetryPolicy`).
+        bounded-backoff retries (:class:`DispatchRetryPolicy`). Each
+        attempt's jitted call and wait is the ``dispatch`` span.
 
         The refine loop DONATES the token buffer off-CPU, so a retry
         cannot replay the same device array — when retries are possible
@@ -848,11 +862,14 @@ class WarmStartScheduler:
                     self._dispatch_fault_hook(mb, attempt)
                 if attempt > 0 and x_backup is not None:
                     x = jnp.asarray(x_backup)
-                out = self._refine_loop(
-                    self.flow_params, flow_keys, x, jnp.asarray(ts),
-                    jnp.asarray(hs), jnp.asarray(active),
-                    jnp.asarray(key_idx))
-                return jax.block_until_ready(out)
+                with self.tracer.span("dispatch", track="refine_dispatch",
+                                      profile=_profile_name("dispatch", k),
+                                      micro_batch=k, attempt=attempt):
+                    out = self._refine_loop(
+                        self.flow_params, flow_keys, x, jnp.asarray(ts),
+                        jnp.asarray(hs), jnp.asarray(active),
+                        jnp.asarray(key_idx))
+                    return jax.block_until_ready(out)
             except Exception as err:  # noqa: BLE001 — device faults vary
                 if attempt >= policy.max_retries:
                     self._c_dispatch_failures.inc()
@@ -864,19 +881,22 @@ class WarmStartScheduler:
                 sleep(policy.backoff_s(attempt))
         raise AssertionError("unreachable")  # pragma: no cover
 
-    def _stage_refine(self, mb: MicroBatch, x, flow_keys):
-        """Flow stage for one micro-batch: one jitted scan dispatch over
+    def _stage_refine(self, mb: MicroBatch, x, flow_keys,
+                      k: Optional[int] = None):
+        """Flow stage for micro-batch ``k``: one jitted scan dispatch over
         the per-row masked schedule. Distilled-tier micro-batches route
         to :meth:`_stage_distill` instead."""
         if mb.tier == DISTILLED_TIER:
-            return self._stage_distill(mb, x)
+            return self._stage_distill(mb, x, k)
         harvest = None
         if self.pair_buffer is not None:
             # snapshot the drafts BEFORE dispatch: the refine loop
             # donates the token buffer off-CPU
             harvest = np.asarray(x)
         span = self.tracer.span("refine", track="refine_dispatch",
-                                bucket=mb.bucket_len, rows=mb.rows,
+                                profile=_profile_name("refine", k),
+                                micro_batch=k, bucket=mb.bucket_len,
+                                rows=mb.rows,
                                 padded_rows=mb.padded_rows, tier=mb.tier,
                                 key=str(mb.compile_key))
         with span as sp:
@@ -898,11 +918,11 @@ class WarmStartScheduler:
                 mb.row_t0s, 1.0 / self.cold_nfe, self.cold_nfe)
             sp["nfe"] = len(ts)
             if self.fused_block > 1:
-                k = min(self.fused_block, len(ts))
-                self._c_fused_blocks.inc(-(-len(ts) // k))
+                blk = min(self.fused_block, len(ts))
+                self._c_fused_blocks.inc(-(-len(ts) // blk))
                 self._c_fused_steps.inc(len(ts))
             x = self._dispatch_refine(mb, x, flow_keys, ts, hs, active,
-                                      key_idx)
+                                      key_idx, k)
             # observed NFE = what the executed schedule actually spent:
             # the scan length for the batch (cross-checked against an
             # independent warm_nfe(cold_nfe, min t0) recomputation — the
@@ -936,14 +956,16 @@ class WarmStartScheduler:
                     harvest, np.asarray(x), mb.row_t0s, mask=mb.row_mask)
         return x, t_flow
 
-    def _stage_distill(self, mb: MicroBatch, x):
+    def _stage_distill(self, mb: MicroBatch, x, k: Optional[int] = None):
         """Distilled-tier flow stage: K = ``distilled_nfe`` steps of the
         distilled head through the same masked row scan, keyed on the
         disjoint DISTILL_STREAM. No NFE-guarantee gates run here — the
         tier's contract is the probe-score quality floor (checked by the
         caller via :meth:`_distill_gate`), not a schedule bound."""
         span = self.tracer.span("distill", track="refine_dispatch",
-                                bucket=mb.bucket_len, rows=mb.rows,
+                                profile=_profile_name("distill", k),
+                                micro_batch=k, bucket=mb.bucket_len,
+                                rows=mb.rows,
                                 padded_rows=mb.padded_rows, tier=mb.tier,
                                 key=str(mb.compile_key))
         with span as sp:
@@ -967,10 +989,14 @@ class WarmStartScheduler:
             seeds, idx = self._mb_row_streams(mb)
             dkeys = _derive_distill_keys(jnp.asarray(seeds), jnp.asarray(idx))
             try:
-                out = self._distill_loop(
-                    self.distilled_params, dkeys, x, jnp.asarray(ts),
-                    jnp.asarray(hs), jnp.asarray(active), jnp.asarray(key_idx))
-                x = jax.block_until_ready(out)
+                with self.tracer.span("dispatch", track="refine_dispatch",
+                                      profile=_profile_name("dispatch", k),
+                                      micro_batch=k, attempt=0):
+                    out = self._distill_loop(
+                        self.distilled_params, dkeys, x, jnp.asarray(ts),
+                        jnp.asarray(hs), jnp.asarray(active),
+                        jnp.asarray(key_idx))
+                    x = jax.block_until_ready(out)
             except Exception as err:  # noqa: BLE001 — device faults vary
                 self._c_dispatch_failures.inc()
                 raise DispatchFailure(mb.compile_key, 1, err) from err
@@ -1506,41 +1532,45 @@ class WarmStartScheduler:
         pre-pass runs HERE, per flushed bucket — requests without a t0
         override are drafted+scored in one batch and the drafts reused
         by the pipeline, exactly as the batch path's global pre-pass
-        does per bucket."""
-        occupancy = fb.rows
-        self.tracer.instant("bucket_flush", track="flush", reason=reason,
-                            bucket=fb.bucket_len, rows=occupancy,
-                            requests=len(fb.requests))
-        self.metrics.counter("serve.flush", reason=reason).inc()
-        self.metrics.histogram(
-            "bucket.flush_rows", buckets=(1, 2, 4, 8, 16, 32, 64, 128),
-            bucket=fb.bucket_len).observe(occupancy)
-        reqs = fb.flush()               # deadline order
-        predrafted = None
-        if self.t0_policy is not None:
-            reqs, predrafted, prep, accepted = self._policy_prepass(reqs)
-            stats["prepass_time_s"] += prep["prepass_time_s"]
-            # speculatively accepted requests skip packing entirely; the
-            # serving loop yields them as ACCEPTED_DRAFT terminals
-            for acc in accepted:
-                acc["reason"] = reason
-                acc["flushed_s"] = now
-            stats["accepted_pending"].extend(accepted)
-        batches = pack_requests(
-            reqs, cold_nfe=self.cold_nfe, default_t0=self.default_t0,
-            max_rows=self.max_rows, min_bucket=self.min_bucket,
-            max_bucket=self.max_bucket, row_quantum=self.row_quantum,
-            row_multiple=self._row_multiple, t0_bin_width=self.t0_bin_width,
-            distilled_nfe=self.distilled_nfe)
-        for mb in batches:
-            for span in mb.spans:
-                self.tracer.instant(
-                    "request_packed", track="flush",
-                    flow_id=span.request.root_id, flow_ph="t",
-                    request_id=span.request.root_id, bucket=mb.bucket_len,
-                    reason=reason)
-        return [{"mb": mb, "predrafted": predrafted, "reason": reason,
-                 "flushed_s": now} for mb in batches]
+        does per bucket. The whole edge is the ``flush`` span."""
+        with self.tracer.span("flush", track="flush",
+                              profile=_profile_name("flush"), reason=reason,
+                              bucket=fb.bucket_len):
+            occupancy = fb.rows
+            self.tracer.instant("bucket_flush", track="flush", reason=reason,
+                                bucket=fb.bucket_len, rows=occupancy,
+                                requests=len(fb.requests))
+            self.metrics.counter("serve.flush", reason=reason).inc()
+            self.metrics.histogram(
+                "bucket.flush_rows", buckets=(1, 2, 4, 8, 16, 32, 64, 128),
+                bucket=fb.bucket_len).observe(occupancy)
+            reqs = fb.flush()               # deadline order
+            predrafted = None
+            if self.t0_policy is not None:
+                reqs, predrafted, prep, accepted = self._policy_prepass(reqs)
+                stats["prepass_time_s"] += prep["prepass_time_s"]
+                # speculatively accepted requests skip packing entirely; the
+                # serving loop yields them as ACCEPTED_DRAFT terminals
+                for acc in accepted:
+                    acc["reason"] = reason
+                    acc["flushed_s"] = now
+                stats["accepted_pending"].extend(accepted)
+            batches = pack_requests(
+                reqs, cold_nfe=self.cold_nfe, default_t0=self.default_t0,
+                max_rows=self.max_rows, min_bucket=self.min_bucket,
+                max_bucket=self.max_bucket, row_quantum=self.row_quantum,
+                row_multiple=self._row_multiple,
+                t0_bin_width=self.t0_bin_width,
+                distilled_nfe=self.distilled_nfe)
+            for mb in batches:
+                for span in mb.spans:
+                    self.tracer.instant(
+                        "request_packed", track="flush",
+                        flow_id=span.request.root_id, flow_ph="t",
+                        request_id=span.request.root_id, bucket=mb.bucket_len,
+                        reason=reason)
+            return [{"mb": mb, "predrafted": predrafted, "reason": reason,
+                     "flushed_s": now} for mb in batches]
 
     def serve_stream(
         self,
@@ -1662,7 +1692,12 @@ class WarmStartScheduler:
         # against it, never from parallel hand-rolled dicts
         m0 = self._jit_cache_snapshot()
         wall0 = clock.time()
+        # micro-batch ids, taken when a micro-batch is popped for drafting
+        # so that all of its spans carry the id its requests will carry
         mb_index = itertools.count()
+        # the open `wait` span of an unbroken stretch of polling with
+        # nothing to dispatch (one span per stretch, not per sleep)
+        waiting = None
         # terminal-status bookkeeping: every admitted ROOT request id
         # lands in `resolved` exactly once, with exactly one terminal
         # CompletedRequest yielded for it (conservation is checked in
@@ -1674,6 +1709,14 @@ class WarmStartScheduler:
 
         def count_terminal(status: str, priority: str) -> None:
             m.counter("serve.terminal", status=status, priority=priority).inc()
+
+        def end_wait() -> None:
+            """Close the idle stretch: the loop found work, or is about to
+            yield (time spent in the caller is no program span's)."""
+            nonlocal waiting
+            if waiting is not None:
+                waiting.__exit__(None, None, None)
+                waiting = None
 
         def class_deadline(req: ServeRequest) -> Optional[float]:
             """arrival + slo * class factor, or None for classes whose
@@ -1694,6 +1737,7 @@ class WarmStartScheduler:
             root = req.root_id
             if root in resolved:
                 return None
+            end_wait()          # the caller yields the result
             resolved.add(root)
             originals.pop(root, None)
             part = partials.pop(root, None)
@@ -1771,6 +1815,7 @@ class WarmStartScheduler:
                 if fb is not None and fb.would_overflow(
                         piece.num_samples, max_rows=self.max_rows,
                         unit=unit):
+                    end_wait()
                     ready.extend(self._flush_bucket(fb, "full", now, stats))
                     fb = None
                 if fb is None:
@@ -1794,6 +1839,7 @@ class WarmStartScheduler:
                        for s in pending["mb"].spans):
                     m.counter("serve.dropped_micro_batches").inc()
                     continue
+                pending["k"] = next(mb_index)
                 return pending
             return None
 
@@ -1810,22 +1856,27 @@ class WarmStartScheduler:
             nonlocal draft_total, flow_total, t_first, distill_min_score
             draft_total += t_draft
             flow_total += t_flow
-            mb = pending["mb"]
-            k = next(mb_index)
+            mb, k = pending["mb"], pending["k"]
             # quality floor for distilled micro-batches, BEFORE the clock
             # reads: the probe eval is part of serving the micro-batch
             gate = (self._distill_gate(mb, x)
                     if mb.tier == DISTILLED_TIER else None)
             finished_s = clock.time()
-            m.histogram("serve.queue_wait_s").observe(
-                finished_s - pending["flushed_s"])
+            # queue wait: from each packed request's arrival to the start
+            # of its micro-batch's refine dispatch
+            for span in mb.spans:
+                m.histogram("serve.queue_wait_s").observe(
+                    pending["dispatch_s"] - span.request.arrival_s)
             mb_reports.append({
                 "micro_batch": k, "bucket_len": mb.bucket_len,
                 "rows": mb.rows, "padded_rows": mb.padded_rows,
                 "t0": mb.t0, "t0_spans": list(mb.t0_spans),
                 "nfe": mb.n_steps, "tier": mb.tier,
                 "flush_reason": pending["reason"],
-                "queue_wait_s": finished_s - pending["flushed_s"],
+                # the stream clock's times of its flush, the start of its
+                # refine dispatch, and its completion
+                "flushed_s": pending["flushed_s"],
+                "dispatch_s": pending["dispatch_s"], "done_s": finished_s,
                 "draft_time_s": t_draft, "flow_time_s": t_flow,
             })
             x_host = np.asarray(x)
@@ -1998,6 +2049,7 @@ class WarmStartScheduler:
                                       idle_timeout_s=idle_timeout_s,
                                       max_rows=self.max_rows, unit=unit))
                         if reason:
+                            end_wait()
                             ready.extend(
                                 self._flush_bucket(fb, reason, now, stats))
                             del filling[fkey]
@@ -2049,6 +2101,7 @@ class WarmStartScheduler:
                                        latency_ms=latency * 1e3)
                         if t_first is None:
                             t_first = now_a
+                        end_wait()
                         yield CompletedRequest(
                             request_id=req.request_id,
                             tokens=np.asarray(acc["tokens"])[:, :req.seq_len],
@@ -2070,9 +2123,16 @@ class WarmStartScheduler:
                             draft_fut = pool.submit(
                                 self._stage_keys_and_draft,
                                 draft_pending["mb"],
-                                draft_pending["predrafted"])
+                                draft_pending["predrafted"],
+                                draft_pending["k"])
                     if draft_fut is not None:
-                        x, flow_keys, t_draft = draft_fut.result()
+                        end_wait()
+                        k = draft_pending["k"]
+                        with tracer.span(
+                                "draft_wait", track="refine_dispatch",
+                                profile=_profile_name("draft_wait", k),
+                                micro_batch=k):
+                            x, flow_keys, t_draft = draft_fut.result()
                         current, draft_fut, draft_pending = \
                             draft_pending, None, None
                         if ready:
@@ -2081,10 +2141,12 @@ class WarmStartScheduler:
                                 draft_fut = pool.submit(
                                     self._stage_keys_and_draft,
                                     draft_pending["mb"],
-                                    draft_pending["predrafted"])
+                                    draft_pending["predrafted"],
+                                    draft_pending["k"])
+                        current["dispatch_s"] = clock.time()
                         try:
                             x, t_flow = self._stage_refine(
-                                current["mb"], x, flow_keys)
+                                current["mb"], x, flow_keys, k)
                         except DispatchFailure:
                             # fault isolation: the retry budget is spent —
                             # fail ONLY this micro-batch's requests and
@@ -2097,14 +2159,23 @@ class WarmStartScheduler:
                                 if item is not None:
                                     yield item
                             continue
-                        for item in complete(current, x, t_draft, t_flow):
+                        with tracer.span("complete", track="refine_dispatch",
+                                         profile=_profile_name("complete", k),
+                                         micro_batch=k):
+                            items = complete(current, x, t_draft, t_flow)
+                        for item in items:
                             yield item
                         continue
                     if source_done and not filling and not ready \
                             and draft_fut is None:
                         break
+                    if waiting is None:
+                        waiting = tracer.span("wait", track="admission",
+                                              profile=_profile_name("wait"))
+                        waiting.__enter__()
                     clock.sleep(poll_interval_s)
         finally:
+            end_wait()
             self._stream_clock = None
 
         wall = clock.time() - wall0

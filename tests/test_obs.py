@@ -6,7 +6,11 @@ integration contracts — the registry terminal ledger matches
 ``stream_report`` exactly (conservation), every request's flow chain
 runs admission→terminal, and tracing never perturbs served tokens."""
 
+import glob
 import json
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -108,6 +112,73 @@ def test_null_tracer_is_inert_but_api_compatible():
     assert tr.enabled is False
     assert len(tr) == 0 and tr.records() == []
     assert tr.emitted == 0 and tr.dropped == 0
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a CPU ``jax.profiler`` trace; its result and the
+    host plane's events as ``(line, name, start_ns, end_ns)``."""
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    events = [(line.name, e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for plane in data.planes if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    return out, events
+
+
+def test_profiler_sink_puts_spans_on_the_profiler_clock(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: jnp.tanh(x @ x))
+    x = jnp.ones((32, 32))
+    jax.block_until_ready(f(x))                     # compiled before
+    tr = SpanTracer(profiler=True)
+
+    def work():
+        with tr.span("dispatch", track="refine_dispatch",
+                     profile="serve.dispatch#3"):
+            jax.block_until_ready(f(x))
+        with tr.span("plain"):
+            pass
+        tr.instant("mark")
+
+    _, events = _profiled(tmp_path, work)
+    span, = [(s, e) for _, n, s, e in events if n == "serve.dispatch#3"]
+    assert any(n == "plain" for _, n, _, _ in events)
+    assert not any(n == "mark" for _, n, _, _ in events)  # ring only
+    # the jitted call's own host events lie inside the span: one clock
+    inner = [(s, e) for _, n, s, e in events
+             if n.startswith("PjitFunction") or n == "dot_general"]
+    assert inner and all(span[0] <= s and e <= span[1] for s, e in inner)
+    # the ring records as it always did
+    assert [r.name for r in tr.records()] == ["dispatch", "plain", "mark"]
+
+
+def test_obs_imports_without_jax():
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "import repro.obs as o\n"
+            "t = o.SpanTracer()\n"
+            "with t.span('s', profile='serve.s'): pass\n"
+            "assert [r.name for r in t.records()] == ['s']\n"
+            "try:\n"
+            "    o.SpanTracer(profiler=True)\n"
+            "except ImportError:\n"
+            "    print('ok')\n")
+    env = dict(os.environ,
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=60)
+    assert p.returncode == 0, p.stderr
+    assert p.stdout.strip() == "ok"
 
 
 # ---------------------------------------------------------------------------
@@ -289,21 +360,19 @@ def test_registry_concurrent_increment_is_exact():
     assert reg.histogram("lat").count == n_threads * per_thread
 
 
-def test_render_text_and_dump_json(tmp_path):
+def test_dump_json_round_trips_the_snapshot(tmp_path):
     reg = MetricsRegistry()
     reg.counter("c", k="v").inc(2)
     reg.gauge("g").set(1.5)
     reg.histogram("h", buckets=(1.0,)).observe(0.5)
-    text = reg.render_text()
-    assert "c{k=v} 2" in text
-    assert "g 1.5" in text
-    assert "h count=1" in text
 
     path = tmp_path / "metrics.json"
     reg.dump_json(str(path))
     loaded = json.loads(path.read_text())
     assert loaded == reg.snapshot()
     assert loaded["counters"]["c{k=v}"] == 2
+    assert loaded["gauges"]["g"] == 1.5
+    assert loaded["histograms"]["h"]["count"] == 1
 
 
 def test_periodic_logger_emits_delta_lines():
@@ -408,7 +477,7 @@ def test_trace_chains_cover_every_ledger_request(tmp_path):
     assert statuses == ["completed", "completed", "shed", "shed"]
 
 
-def test_tracing_does_not_perturb_served_tokens():
+def test_tracing_does_not_perturb_served_tokens(tmp_path):
     import numpy as np
 
     base = {c.request_id: c for c in
@@ -417,14 +486,83 @@ def test_tracing_does_not_perturb_served_tokens():
     traced_sched = _make_scheduler(max_rows=8, tracer=tracer)
     traced = {c.request_id: c for c in
               traced_sched.serve_stream(_mixed_requests())}
-    assert set(traced) == set(base)
-    for rid in base:
-        np.testing.assert_array_equal(traced[rid].tokens, base[rid].tokens)
-        assert traced[rid].nfe == base[rid].nfe
+    # the profiler sink, with a profiler session running (the same
+    # scheduler: its programs are compiled already)
+    traced_sched.tracer = SpanTracer(profiler=True)
+    profiled, _ = _profiled(tmp_path, lambda: {
+        c.request_id: c for c in
+        traced_sched.serve_stream(_mixed_requests())})
+    for run in (traced, profiled):
+        assert set(run) == set(base)
+        for rid in base:
+            np.testing.assert_array_equal(run[rid].tokens, base[rid].tokens)
+            assert run[rid].nfe == base[rid].nfe
     assert tracer.emitted > 0  # the traced run really did record spans
     tracks = {r.track for r in tracer.records()}
     assert {"admission", "draft_worker", "refine_dispatch",
             "flush", "terminal"} <= tracks
+
+
+@pytest.mark.parametrize("fused_block", [1, 2])
+def test_stream_spans_carry_their_micro_batch_id(tmp_path, fused_block):
+    """Every micro-batch's spans on the profiler's host plane carry the
+    ``#k`` its requests receive as ``CompletedRequest.micro_batch``, the
+    worker's draft span included; the ring's spans carry it as an arg.
+    The fused path's step blocks leave the id alone."""
+    tracer = SpanTracer(profiler=True)
+    sched = _make_scheduler(max_rows=8, tracer=tracer,
+                            fused_block=fused_block)
+    out, events = _profiled(tmp_path,
+                            lambda: list(sched.serve_stream(_mixed_requests())))
+    ks = {c.micro_batch for c in out}
+    assert ks == {b["micro_batch"] for b in sched.stream_report["batches"]}
+    assert len(ks) > 1
+    for stage in ("draft", "draft_wait", "refine", "dispatch", "complete"):
+        got = [int(n.split("#")[1]) for _, n, _, _ in events
+               if n.startswith(f"serve.{stage}#")]
+        assert sorted(got) == sorted(ks), stage
+    assert any(n == "serve.flush" for _, n, _, _ in events)
+    for r in tracer.records():
+        if r.name in ("draft", "refine", "dispatch", "complete"):
+            assert r.args["micro_batch"] in ks
+
+
+def test_queue_wait_is_arrival_to_refine_dispatch():
+    """``serve.queue_wait_s`` observes, per packed request, the stream
+    clock's start of its micro-batch's refine dispatch less its arrival;
+    each batch entry carries its flush, dispatch and completion times."""
+    from repro.serving import ServeRequest
+
+    class TickingClock:
+        """Advances 0.25 s at every reading, so each stage has its time."""
+
+        def __init__(self):
+            self.t = 10.0
+
+        def time(self):
+            self.t += 0.25
+            return self.t
+
+        def sleep(self, dt):
+            self.t += dt
+
+    sched = _make_scheduler(max_rows=8)
+    arrivals = {0: 9.0, 1: 9.5, 2: 9.75, 3: 8.0}
+    reqs = [ServeRequest(request_id=r.request_id, seq_len=r.seq_len,
+                         num_samples=r.num_samples, seed=r.seed, t0=r.t0,
+                         arrival_s=arrivals[r.request_id])
+            for r in _mixed_requests()]
+    out = {c.request_id: c for c in
+           sched.serve_stream(reqs, clock=TickingClock())}
+    batches = {b["micro_batch"]: b for b in sched.stream_report["batches"]}
+    for b in batches.values():
+        assert b["flushed_s"] < b["dispatch_s"] < b["done_s"]
+        assert "queue_wait_s" not in b
+    waits = [batches[c.micro_batch]["dispatch_s"] - arrivals[rid]
+             for rid, c in out.items()]
+    h = sched.metrics.snapshot()["histograms"]["serve.queue_wait_s"]
+    assert h["count"] == len(waits)
+    assert h["sum"] == pytest.approx(sum(waits))
 
 
 def test_distilled_tier_spans_and_counters_in_registry():

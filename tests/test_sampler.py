@@ -140,3 +140,46 @@ def test_refine_schedule_partial_final_step_lands_on_one(t0, cold_nfe):
     assert ts[-1] + hs[-1] == pytest.approx(1.0, abs=1e-6)
     # times are the uniform grid from t0
     np.testing.assert_allclose(ts, t0 + np.arange(n) * h, rtol=1e-5, atol=1e-7)
+
+
+def test_named_scopes_leave_refine_tokens_bit_identical():
+    """``scan_refine_loop_rows`` tags its body ``backbone`` and
+    ``sample_step``; the tokens equal those of the same loop written
+    without scopes, bit for bit, and the compiled program keeps both
+    scopes in its op metadata."""
+    from repro.configs.dfm_dit import tiny_config
+    from repro.core.sampler import (
+        make_euler_one_step_rows, refine_schedule_rows, scan_refine_loop_rows,
+    )
+    from repro.models import build_model
+
+    cfg = tiny_config(vocab_size=27, seq_len=16).replace(
+        num_layers=1, d_model=64, num_heads=4, num_kv_heads=4, d_ff=128)
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    one_step = make_euler_one_step_rows(WarmStartPath(t0=0.0))
+    ts, hs, active, key_idx, _ = refine_schedule_rows(
+        np.array([0.5, 0.8, 0.8, 0.9]), 1 / 16, 16)
+    keys = jax.random.split(jax.random.key(3), 4)
+    x0 = jax.random.randint(jax.random.key(4), (4, 16), 0, 27)
+    logits_fn = lambda x, t: model.dfm_apply(params, x, t)  # noqa: E731
+
+    def plain(x, keys, ts, hs, active, key_idx):
+        def body(x, inp):
+            t, h, act, idx = inp
+            k = jax.vmap(jax.random.fold_in)(keys, idx)
+            x_next = one_step(k, logits_fn(x, t), x, t, h)
+            return jnp.where(act[:, None], x_next, x), None
+        return jax.lax.scan(body, x, (ts, hs, active, key_idx))[0]
+
+    def scoped(x, keys, ts, hs, active, key_idx):
+        return scan_refine_loop_rows(logits_fn, one_step, x, keys, ts, hs,
+                                     active, key_idx)
+
+    args = (x0, keys, jnp.asarray(ts), jnp.asarray(hs), jnp.asarray(active),
+            jnp.asarray(key_idx))
+    got = jax.jit(scoped)(*args)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(jax.jit(plain)(*args)))
+    text = jax.jit(scoped).lower(*args).compile().as_text()
+    assert "/backbone/" in text and "/sample_step/" in text
