@@ -17,11 +17,15 @@ Phases (one chip):
   ``ACCEPTED_DRAFT`` under speculation) with no dispatch retry or failure,
   at exactly ``warm_nfe(cold_nfe, t0)`` refine steps, with tokens in
   ``[0, V)``. A second serve of the same requests, and the batch path
-  (``serve_requests``), must give the same bits as the stream.
+  (``serve_requests``), must give the same bits as the stream. Each
+  refine key's trace must have taken the attention the ``"auto"`` rule
+  gives its bucket: the fused kernel on the chip.
 * ``backbone_logits``: one micro-batch's backbone logits on the chip
   against a float32 forward on the host CPU at "highest" matmul precision.
 * ``kernels``: every Pallas kernel compiled for the chip (the lowered HLO
-  holds ``tpu_custom_call``) and checked against its reference.
+  holds ``tpu_custom_call``) and checked against its reference; flash
+  attention as the refine calls it, at DFM-DiT's and StarCoder2-3B's head
+  widths (grouped KV heads).
 
 Each phase prints its wall seconds, its compile seconds and compile count,
 and the device's peak bytes in use so far. These are bring-up facts, not
@@ -56,14 +60,15 @@ from repro.drafting import (  # noqa: E402
 )
 from repro.drafting.quality import DEFAULT_TIERS  # noqa: E402
 from repro.kernels import DraftDecoder, resolve_interpret  # noqa: E402
-from repro.kernels.flash_attn import (  # noqa: E402
-    flash_attention, flash_attention_ref,
-)
+from repro.kernels.flash_attn import flash_attention_ref  # noqa: E402
 from repro.kernels.ws_fused import ws_fused_steps  # noqa: E402
 from repro.kernels.ws_step import (  # noqa: E402
     seed_from_key, threefry_gumbel, ws_step, ws_step_ref_streamed,
 )
 from repro.models import LSTMConfig, LSTMModel, build_model  # noqa: E402
+from repro.models.attention import (  # noqa: E402
+    attention_impl, fused_attention,
+)
 from repro.serving import (  # noqa: E402
     ACCEPTED_DRAFT, COMPLETED, ServeRequest, WarmStartScheduler,
 )
@@ -250,6 +255,21 @@ def serve_checked(sched, reqs, vocab: int, *, speculative: bool) -> dict:
             "micro_batches": sched.stream_report["num_micro_batches"]}
 
 
+def require_attention(sched, cfg) -> dict:
+    """Each refine key's trace took the attention the ``"auto"`` rule
+    gives its bucket (on the chip: the fused kernel from 128 tokens)."""
+    taken = {}
+    for key, entry in sched.stream_report["jit_cache"]["per_key"].items():
+        bucket = int(key.strip("()").split(",")[0])
+        want = attention_impl(cfg, mode="bidir", cached=False, window=None,
+                              seq=bucket)
+        require(entry.get("attention") == want,
+                f"refine key {key}: attention {entry.get('attention')!r}, "
+                f"not {want!r}")
+        taken[key] = want
+    return taken
+
+
 def serve_phase(cfg, *, seed: int, traffic=TRAFFIC,
                 max_rows: int = MAX_ROWS) -> None:
     """The main path at ``cfg``: fixed t0, then adaptive t0 with
@@ -268,6 +288,7 @@ def serve_phase(cfg, *, seed: int, traffic=TRAFFIC,
     with phase("serve_fixed", {"t0": FIXED_T0, "requests": len(reqs)}) as rep:
         sched = WarmStartScheduler(**kw, default_t0=FIXED_T0)
         rep.update(serve_checked(sched, reqs, vocab, speculative=False))
+        rep["attention"] = require_attention(sched, cfg)
 
     with phase("serve_adaptive", {"t0": "auto", "speculative": True,
                                   "requests": len(reqs)}) as rep:
@@ -407,23 +428,44 @@ def check_ws_fused(seed: int, rows: int, vocab: int, interpret: bool,
                 f"ws_fused hw-PRNG V={vocab}: tokens outside [0, V)")
 
 
-def check_flash_attn(seed: int, seq: int, heads: int, head_dim: int,
-                     interpret: bool) -> dict:
-    ks = jax.random.split(jax.random.key(seed), 3)
-    q, k, v = (jax.random.normal(kk, (1, seq, heads, head_dim)) for kk in ks)
+# query heads, KV heads, head width: DFM-DiT's and StarCoder2-3B's
+FLASH_HEADS = ((CONFIG.num_heads, CONFIG.num_kv_heads, CONFIG.head_dim),
+               (24, 2, 128))
 
-    def fa(q, k, v):
-        return flash_attention(q, k, v, causal=False, interpret=interpret)
 
-    require_kernel(fa, (q, k, v), interpret, "flash_attn bidirectional")
-    out = np.asarray(jax.jit(fa)(q, k, v))
-    with jax.default_matmul_precision("highest"):
-        ref = np.asarray(jax.jit(
-            lambda q, k, v: flash_attention_ref(q, k, v, causal=False))(q, k, v))
-    err = float(np.abs(out - ref).max())
-    require(np.allclose(out, ref, atol=FLASH_TOL, rtol=FLASH_TOL),
-            f"flash_attn: max abs error {err:.3g} beyond {FLASH_TOL}")
-    return {"max_abs": err, "tol": FLASH_TOL}
+def check_flash_attn(seed: int, seq: int, interpret: bool,
+                     heads=FLASH_HEADS) -> dict:
+    """The attention the refine takes, as the model calls it: the
+    ``"auto"`` rule picks the fused kernel for a bidirectional bucket of
+    ``seq`` on the chip, and ``fused_attention`` (KV heads read through
+    the index map) matches a float32 reference at "highest" precision."""
+    if not interpret:
+        impl = attention_impl(CONFIG, mode="bidir", cached=False,
+                              window=None, seq=seq)
+        require(impl == "fused", f"attn_impl auto takes {impl!r} at {seq}")
+    rep = {}
+    for h, kh, d in heads:
+        ks = jax.random.split(jax.random.key(seed), 3)
+        q = jax.random.normal(ks[0], (1, seq, h, d))
+        k, v = (jax.random.normal(kk, (1, seq, kh, d)) for kk in ks[1:])
+        scale = 1.0 / math.sqrt(d)
+
+        def fa(q, k, v):
+            return fused_attention(q, k, v, scale, interpret)
+
+        what = f"flash_attn bidirectional {h}/{kh}x{d}"
+        require_kernel(fa, (q, k, v), interpret, what)
+        out = np.asarray(jax.jit(fa)(q, k, v))
+        with jax.default_matmul_precision("highest"):
+            ref = np.asarray(jax.jit(
+                lambda q, k, v: flash_attention_ref(
+                    q, jnp.repeat(k, h // kh, 2), jnp.repeat(v, h // kh, 2),
+                    causal=False))(q, k, v))
+        err = float(np.abs(out - ref).max())
+        require(np.allclose(out, ref, atol=FLASH_TOL, rtol=FLASH_TOL),
+                f"{what}: max abs error {err:.3g} beyond {FLASH_TOL}")
+        rep[f"{h}/{kh}x{d}"] = {"max_abs": err, "tol": FLASH_TOL}
+    return rep
 
 
 def check_draft_decode(seed: int, interpret: bool, *, batch: int = 4,
@@ -487,8 +529,7 @@ def kernel_phase(*, seed: int, interpret: bool = False, hw_prng: bool = True,
                 seed, 8192, CONFIG.vocab_size, 8, interpret)
         for rows, vocab in ws_shapes:
             check_ws_fused(seed, rows, vocab, interpret, hw_prng)
-        rep["flash_attn"] = check_flash_attn(seed, flash_seq, CONFIG.num_heads,
-                                             CONFIG.head_dim, interpret)
+        rep["flash_attn"] = check_flash_attn(seed, flash_seq, interpret)
         batch, seq, max_len = draft_shape
         rep["draft_decode"] = check_draft_decode(
             seed, interpret, batch=batch, seq=seq, max_len=max_len)

@@ -131,11 +131,12 @@ class ModelConfig:
     # DESIGN.md §4 policy). None = faithful (full attention everywhere).
     long_context_window: Optional[int] = 8192
 
-    # attention implementation: "xla" (einsum, O(S*T) scores — baseline) |
-    # "chunked" (flash-style online softmax over key chunks, O(S*chunk)
-    # scores — §Perf iteration; the Pallas kernel is the TPU execution
-    # path and is validated against both).
-    attn_impl: str = "xla"
+    # attention implementation: "auto" (the fused Pallas kernel where
+    # models/attention.py::attention_impl allows it — bidirectional, no
+    # cache, one TPU — else "xla") | "xla" (einsum, O(S*T) scores) |
+    # "chunked" (flash-style online softmax over key chunks in XLA,
+    # O(S*chunk) scores — §Perf iteration).
+    attn_impl: str = "auto"
     attn_chunk: int = 1024
     # MLA decode: absorb the latent up-projections into the query/output
     # (DeepSeek-V2 §"absorbed" inference trick) instead of expanding the

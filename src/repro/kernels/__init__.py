@@ -4,7 +4,10 @@
   ws_fused     — multi-step fused refine megakernel: K consecutive Euler
                  warm-start sampling steps in ONE dispatch, token state and
                  accumulators carried in VMEM scratch across steps
-  flash_attn   — blockwise attention with sliding-window block skipping
+  flash_attn   — flash attention: the refine's bidirectional attention
+                 with the whole key range in one VMEM block, KV heads by
+                 the index map; online softmax with sliding-window block
+                 skipping past that
   draft_decode — fixed-reduction-order decode-step kernels for the AR
                  draft engine (bit-identical batched prefill)
 
@@ -23,6 +26,16 @@ from typing import Optional
 import jax
 
 
+def default_platform() -> str:
+    """The platform a computation traced now runs on: the default
+    device's where one is set (``jax.default_device``, as a host-side
+    reference on a TPU machine does), else the default backend's."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
+
+
 def resolve_interpret(interpret: Optional[bool]) -> bool:
     """Resolve an ``interpret=None`` kernel argument at trace time.
 
@@ -31,14 +44,14 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     draft_decode so backend detection can't drift between packages.
     """
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        return default_platform() != "tpu"
     return bool(interpret)
 
 
 def is_tpu_backend() -> bool:
     """True when the default JAX backend is a real TPU (trace-time check
     used to auto-select hardware PRNG / compiled kernel paths)."""
-    return jax.default_backend() == "tpu"
+    return default_platform() == "tpu"
 
 
 from repro.kernels.ws_step import (
@@ -53,7 +66,7 @@ from repro.kernels.draft_decode import (
     DraftDecoder, draft_decode_supported,
 )
 
-__all__ = ["resolve_interpret", "is_tpu_backend",
+__all__ = ["default_platform", "resolve_interpret", "is_tpu_backend",
            "ws_step", "make_ws_step_fn", "pick_tiles", "ws_step_ref",
            "ws_step_ref_streamed", "ws_step_streamed_pallas",
            "ws_fused_steps", "make_ws_fused_fn", "pick_tiles_fused",

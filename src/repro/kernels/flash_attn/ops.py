@@ -1,6 +1,7 @@
-"""Jit'd wrapper for the Pallas flash attention kernel: GQA head expansion,
-seq padding to block multiples, head folding, and the interpret switch
-(CPU validation vs TPU execution).
+"""Jit'd wrapper for the Pallas flash attention kernel: the model's
+(B, S, H, D) arrays viewed as (B, S, H*D), seq padding to block
+multiples, block sizes from the shape, and the interpret switch (CPU
+validation vs TPU execution).
 
 ``interpret=None`` (default) goes through the central
 ``kernels.resolve_interpret``: compiled on a real TPU backend, interpret
@@ -15,7 +16,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import resolve_interpret
-from repro.kernels.flash_attn.kernel import flash_attention_pallas
+from repro.kernels.flash_attn.kernel import (
+    LANE, flash_attention_pallas, heads_per_block, pick_blocks,
+)
+
+
+def _padded(n: int, rows: int) -> int:
+    """A length padded to the sublane tile, or to the lane tile past it."""
+    step = rows if n <= LANE else LANE
+    return -(-n // step) * step
 
 
 def flash_attention(
@@ -26,35 +35,40 @@ def flash_attention(
     causal: bool = True,
     window: Optional[int] = None,
     scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
+    mxu_dtype=None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
+    """Attention of q over k, v; query head ``h`` reads KV head
+    ``h // (H // KH)``. Blocks default to ``pick_blocks`` of the padded
+    shape (the whole key range in one block where VMEM holds it). Grouped
+    heads of fewer than 128 lanes (no served model has them) repeat K and
+    V: a lane block holds its own KV heads."""
     interpret = resolve_interpret(interpret)
     b, s, h, d = q.shape
     t, kh = k.shape[1], k.shape[2]
-    if kh != h:                      # GQA: expand kv heads to query heads
-        g = h // kh
-        k = jnp.repeat(k, g, axis=2)
-        v = jnp.repeat(v, g, axis=2)
-
-    bq = min(block_q, max(8, s))
-    bk = min(block_k, max(8, t))
+    hb = heads_per_block(h, d)
+    if hb > 1 and kh != h:
+        k, v = (jnp.repeat(a, h // kh, axis=2) for a in (k, v))
+        kh = h
+    if block_q is None or block_k is None:
+        rows = 8 * max(1, 4 // q.dtype.itemsize)
+        sp, tp = _padded(s, rows), _padded(t, rows)
+        auto_q, auto_k = pick_blocks(sp, tp, hb * d, q.dtype.itemsize, hb)
+        bq, bk = block_q or auto_q, block_k or auto_k
+    else:
+        bq = min(block_q, max(8, s))
+        bk = min(block_k, max(8, t))
     sp = -(-s // bq) * bq
     tp = -(-t // bk) * bk
-    if sp != s:
-        q = jnp.pad(q, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
-    if tp != t:
-        k = jnp.pad(k, ((0, 0), (0, tp - t), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, tp - t), (0, 0), (0, 0)))
 
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sp, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, tp, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, tp, d)
-
+    qf = jnp.pad(q.reshape(b, s, h * d), ((0, 0), (0, sp - s), (0, 0)))
+    kf = jnp.pad(k.reshape(b, t, kh * d), ((0, 0), (0, tp - t), (0, 0)))
+    vf = jnp.pad(v.reshape(b, t, kh * d), ((0, 0), (0, tp - t), (0, 0)))
     out = flash_attention_pallas(
-        qf, kf, vf, causal=causal, window=window, scale=scale,
-        block_q=bq, block_k=bk, seq_q=s, seq_k=t, interpret=interpret,
+        qf, kf, vf, heads=h, kv_heads=kh, causal=causal, window=window,
+        scale=scale, block_q=bq, block_k=bk, seq_k=t, mxu_dtype=mxu_dtype,
+        interpret=interpret,
     )
-    out = out.reshape(b, h, sp, d).transpose(0, 2, 1, 3)
-    return out[:, :s]
+    return out[:, :s].reshape(b, s, h, d)

@@ -7,13 +7,20 @@ Masking semantics:
   mode="causal"  — AR training / prefill
   decode         — single query against a cache of length `pos`
 
-The XLA einsum path below is the reference/dry-run implementation; the
-Pallas flash kernel (kernels/flash_attn) is selected via cfg when running
-on real TPUs and is validated against this path in tests.
+GQA's attention is picked per call by ``attention_impl`` from
+``cfg.attn_impl``: ``"auto"`` (the default) runs the fused Pallas kernel
+(kernels/flash_attn, one key block in VMEM, the score tensor never in
+HBM) for the bidirectional denoiser on one TPU, and the XLA einsum path
+``_sdpa`` everywhere else; ``"xla"`` forces ``_sdpa``; ``"chunked"``
+takes ``_sdpa_chunked`` past ``cfg.attn_chunk``. The fused path is
+checked against ``_sdpa`` in tests, and differentiates through it.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -21,6 +28,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, MLASettings
+from repro.kernels import default_platform
+from repro.kernels.flash_attn import flash_attention
 from repro.models.common import (
     dense, dense_init, init_rmsnorm, rmsnorm, param_dtype,
 )
@@ -63,6 +72,47 @@ def _sdpa(q, k, v, mask, *, scale, softcap=0.0):
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
     return out
+
+
+def _mxu_dtype(dtype):
+    """The operand dtype of ``_sdpa``'s einsums at default precision: on a
+    TPU a float32 einsum is one bf16 pass with float32 accumulation."""
+    if (dtype == jnp.float32 and default_platform() == "tpu"
+            and jax.config.jax_default_matmul_precision in (
+                None, "default", "bfloat16", "BF16_BF16_F32")):
+        return jnp.bfloat16
+    return dtype
+
+
+def _sdpa_bidir(q, k, v, scale):
+    """``_sdpa`` unmasked, on the fused path's layout: q (B,S,H,D), k and
+    v (B,T,KH,D)."""
+    b, s, h, d = q.shape
+    kh = k.shape[2]
+    mask = jnp.ones((b, s, k.shape[1]), bool)
+    out = _sdpa(q.reshape(b, s, kh, h // kh, d), k, v, mask, scale=scale)
+    return out.reshape(b, s, h, -1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def fused_attention(q, k, v, scale, interpret=None):
+    """Bidirectional attention in one Pallas kernel, arithmetic as
+    ``_sdpa``'s (float32 scores and softmax, matmul operands as the einsum
+    at default precision takes them); its VJP is ``_sdpa``'s, recomputed."""
+    return flash_attention(q, k, v, causal=False, scale=scale,
+                           mxu_dtype=_mxu_dtype(q.dtype), interpret=interpret)
+
+
+def _fused_attention_fwd(q, k, v, scale, interpret):
+    return fused_attention(q, k, v, scale, interpret), (q, k, v)
+
+
+def _fused_attention_bwd(scale, interpret, res, g):
+    _, vjp = jax.vjp(functools.partial(_sdpa_bidir, scale=scale), *res)
+    return vjp(g)
+
+
+fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
 
 
 def _sdpa_chunked(q, k, v, q_pos, k_pos, *, scale, softcap=0.0,
@@ -136,6 +186,48 @@ def _sdpa_chunked(q, k, v, q_pos, k_pos, *, scale, softcap=0.0,
 # GQA
 # ---------------------------------------------------------------------------
 
+_taken: contextvars.ContextVar = contextvars.ContextVar(
+    "attention_taken", default=None)
+
+
+@contextlib.contextmanager
+def record_attention():
+    """Yield a list that collects, while a program is traced inside the
+    block, the attention (``attention_impl``'s answer) of each GQA call."""
+    log: list = []
+    token = _taken.set(log)
+    try:
+        yield log
+    finally:
+        _taken.reset(token)
+
+
+def attention_impl(cfg: ModelConfig, *, mode: str, cached: bool,
+                   window: Optional[int], seq: int,
+                   backend: Optional[str] = None,
+                   devices: Optional[int] = None) -> str:
+    """``"fused"``, ``"chunked"`` or ``"xla"``: the attention a GQA call
+    of ``seq`` queries takes. ``"auto"`` fuses where the kernel is the
+    whole of the job: a TPU backend, one device (no mesh in scope),
+    bidirectional, no cache, no softcap, no window narrower than ``seq``
+    and ``seq`` a multiple of 128. ``backend`` and ``devices`` default to
+    ``kernels.default_platform()`` and the size of the mesh in scope."""
+    if cfg.attn_impl == "chunked":
+        return "chunked" if seq > cfg.attn_chunk else "xla"
+    if cfg.attn_impl != "auto":
+        return "xla"
+    if backend is None:
+        backend = default_platform()
+    if devices is None:
+        from repro.distributed.sharding import current_mesh
+        mesh = current_mesh()
+        devices = 1 if mesh is None else mesh.size
+    fused = (backend == "tpu" and devices == 1 and mode == "bidir"
+             and not cached and not cfg.attn_logit_softcap
+             and (window is None or window >= seq) and seq % 128 == 0)
+    return "fused" if fused else "xla"
+
+
 def init_gqa(key, cfg: ModelConfig) -> dict:
     pd = param_dtype(cfg)
     d, hd = cfg.d_model, cfg.head_dim
@@ -179,43 +271,52 @@ def gqa_attention(
         k = apply_rope(k, sin, cos)
     scale = 1.0 / math.sqrt(hd)
 
-    use_chunked = cfg.attn_impl == "chunked" and s > cfg.attn_chunk
+    impl = attention_impl(cfg, mode=mode, cached=cache is not None,
+                          window=window, seq=s)
+    log = _taken.get()
+    if log is not None:
+        log.append(impl)
+    use_chunked = impl == "chunked"
 
     new_cache = None
-    if cache is not None:
-        # write current k/v at positions q_pos into the cache buffer
-        t = cache["k"].shape[1]
-        start = cache["pos"]
-        kbuf = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                            (0, start, 0, 0))
-        vbuf = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                            (0, start, 0, 0))
-        new_cache = {"k": kbuf, "v": vbuf, "pos": start + s}
-        k_full, v_full = kbuf.astype(x.dtype), vbuf.astype(x.dtype)
-        k_pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
-        k_valid = k_pos[0][None, :] < (start + s)
-        qh = q.reshape(b, s, kh, g, hd)
-        if use_chunked:
-            out = _sdpa_chunked(qh, k_full, v_full, q_pos, k_pos, scale=scale,
-                                softcap=cfg.attn_logit_softcap, mode="causal",
-                                window=window, k_valid=k_valid,
-                                chunk=cfg.attn_chunk)
+    with jax.named_scope("attention"):
+        if impl == "fused":
+            out = fused_attention(q, k, v, scale)
+        elif cache is not None:
+            # write current k/v at positions q_pos into the cache buffer
+            t = cache["k"].shape[1]
+            start = cache["pos"]
+            kbuf = jax.lax.dynamic_update_slice(
+                cache["k"], k.astype(cache["k"].dtype), (0, start, 0, 0))
+            vbuf = jax.lax.dynamic_update_slice(
+                cache["v"], v.astype(cache["v"].dtype), (0, start, 0, 0))
+            new_cache = {"k": kbuf, "v": vbuf, "pos": start + s}
+            k_full, v_full = kbuf.astype(x.dtype), vbuf.astype(x.dtype)
+            k_pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
+            k_valid = k_pos[0][None, :] < (start + s)
+            qh = q.reshape(b, s, kh, g, hd)
+            if use_chunked:
+                out = _sdpa_chunked(qh, k_full, v_full, q_pos, k_pos,
+                                    scale=scale,
+                                    softcap=cfg.attn_logit_softcap,
+                                    mode="causal", window=window,
+                                    k_valid=k_valid, chunk=cfg.attn_chunk)
+            else:
+                mask = attn_mask(q_pos, k_pos, mode="causal", window=window,
+                                 k_valid=k_valid)
+                out = _sdpa(qh, k_full, v_full, mask, scale=scale,
+                            softcap=cfg.attn_logit_softcap)
         else:
-            mask = attn_mask(q_pos, k_pos, mode="causal", window=window,
-                             k_valid=k_valid)
-            out = _sdpa(qh, k_full, v_full, mask, scale=scale,
-                        softcap=cfg.attn_logit_softcap)
-    else:
-        k_pos = q_pos
-        qh = q.reshape(b, s, kh, g, hd)
-        if use_chunked:
-            out = _sdpa_chunked(qh, k, v, q_pos, k_pos, scale=scale,
-                                softcap=cfg.attn_logit_softcap, mode=mode,
-                                window=window, chunk=cfg.attn_chunk)
-        else:
-            mask = attn_mask(q_pos, k_pos, mode=mode, window=window)
-            out = _sdpa(qh, k, v, mask, scale=scale,
-                        softcap=cfg.attn_logit_softcap)
+            k_pos = q_pos
+            qh = q.reshape(b, s, kh, g, hd)
+            if use_chunked:
+                out = _sdpa_chunked(qh, k, v, q_pos, k_pos, scale=scale,
+                                    softcap=cfg.attn_logit_softcap, mode=mode,
+                                    window=window, chunk=cfg.attn_chunk)
+            else:
+                mask = attn_mask(q_pos, k_pos, mode=mode, window=window)
+                out = _sdpa(qh, k, v, mask, scale=scale,
+                            softcap=cfg.attn_logit_softcap)
 
     out = out.reshape(b, s, h * hd)
     return dense(p["wo"], out), new_cache

@@ -59,6 +59,7 @@ from repro.serving.batcher import (
 from repro.serving.engine import (
     DispatchFailure, DispatchRetryPolicy, PerNFECostModel,
 )
+from repro.models.attention import record_attention
 from repro.obs import MetricsRegistry, NullTracer, parse_metric_key
 
 
@@ -621,6 +622,9 @@ class WarmStartScheduler:
         self._queue: List[ServeRequest] = []
         self._next_id = 0
         self._compiled: set = set()     # compile_key accounting
+        # compile-key label -> the attention its refine trace took
+        # ("fused" / "xla"), written while the program is traced
+        self._attention: Dict[str, str] = {}
         # measured latency oracle for the SLO admission loop: per-NFE
         # refine cost EWMA per compile key (+ global fallback), fed by
         # every _stage_refine dispatch; draft-stage cost EWMA beside it
@@ -661,9 +665,14 @@ class WarmStartScheduler:
             # step index; a t0-homogeneous batch reduces bit-exactly to
             # the plain scan_refine_loop schedule.
             logits_fn = lambda xt, tb: self.flow_model.dfm_apply(params, xt, tb)
-            return scan_refine_loop_rows(
-                logits_fn, one_step, x, flow_keys, ts, hs, active, key_idx,
-                fused_block=fused_block, fused_fn=fused_fn)
+            with record_attention() as taken:
+                out = scan_refine_loop_rows(
+                    logits_fn, one_step, x, flow_keys, ts, hs, active,
+                    key_idx, fused_block=fused_block, fused_fn=fused_fn)
+            if taken:   # runs only while tracing: key (bucket, rows, steps)
+                key = (x.shape[1], x.shape[0], ts.shape[0])
+                self._attention[_key_label(key)] = "+".join(sorted(set(taken)))
+            return out
 
         # donate the draft token buffer into the refine loop off-CPU, as
         # the one-shot engine does — it is dead after the dispatch
@@ -1063,9 +1072,10 @@ class WarmStartScheduler:
     def _jit_cache_delta(self, snap) -> dict:
         """The report's ``jit_cache`` section, derived from registry
         counter deltas since ``snap``: aggregate + per-compile-key
-        hit/miss counts and fused-block dispatch totals."""
+        hit/miss counts (with the attention each refine key's trace took,
+        ``"fused"`` or ``"xla"``) and fused-block dispatch totals."""
         deltas = self.metrics.counter_deltas(snap)
-        per_key: Dict[str, Dict[str, int]] = {}
+        per_key: Dict[str, Dict[str, Any]] = {}
         for mkey, v in deltas.items():
             name, labels = parse_metric_key(mkey)
             if name != "jit_cache.per_key":
@@ -1073,6 +1083,8 @@ class WarmStartScheduler:
             entry = per_key.setdefault(
                 _key_from_label(labels["key"]), {"hits": 0, "misses": 0})
             entry["hits" if labels["kind"] == "hit" else "misses"] += v
+            if labels["key"] in self._attention:
+                entry["attention"] = self._attention[labels["key"]]
         return {
             "hits": deltas.get("jit_cache.hits", 0),
             "misses": deltas.get("jit_cache.misses", 0),

@@ -10,6 +10,8 @@ the final partial step, and a 262k vocab. flash_attn sweeps (seq, heads,
 head_dim, GQA ratio, causal/bidir, window).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -297,6 +299,198 @@ def test_flash_attention_matches_model_attention_path():
     sc = jnp.where(mask[:, None], sc, NEG_INF)
     ref = jnp.einsum("bhst,bthd->bshd", jax.nn.softmax(sc, -1), v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
+# ---------------------------------------------------------------------------
+# the fused bidirectional path of the refine (models/attention.py)
+# ---------------------------------------------------------------------------
+
+# query heads, KV heads, head width
+HEAD_SHAPES = {"dfm-dit": (12, 12, 64), "starcoder2-3b": (24, 2, 128)}
+ATOL = {jnp.float32: 3e-5, jnp.bfloat16: 2e-2}
+
+
+def _qkv(b, s, h, kh, d, dtype, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(ks[0], (b, s, h, d)).astype(dtype)
+    k = jax.random.normal(ks[1], (b, s, kh, d)).astype(dtype)
+    v = jax.random.normal(ks[2], (b, s, kh, d)).astype(dtype)
+    return q, k, v
+
+
+@pytest.mark.parametrize("grid", ["one-key-block", "multi-key-block"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("s", [128, 512])
+@pytest.mark.parametrize("heads", sorted(HEAD_SHAPES))
+def test_fused_bidirectional_matches_sdpa(heads, s, dtype, grid):
+    """The fused path against ``_sdpa`` at the cells' head shapes: the
+    served grid (the whole key range in one block) and the online-softmax
+    grid over several key blocks."""
+    from repro.kernels.flash_attn.kernel import heads_per_block, pick_blocks
+    from repro.models.attention import _sdpa_bidir, fused_attention
+    h, kh, d = HEAD_SHAPES[heads]
+    q, k, v = _qkv(1, s, h, kh, d, dtype)
+    scale = d ** -0.5
+    if grid == "one-key-block":
+        hb = heads_per_block(h, d)
+        assert pick_blocks(s, s, hb * d, jnp.dtype(dtype).itemsize,
+                           hb) == (s, s)
+        out = fused_attention(q, k, v, scale)
+    else:
+        out = flash_attention(q, k, v, causal=False, scale=scale,
+                              block_q=s // 2, block_k=s // 4, interpret=True)
+    ref = _sdpa_bidir(q, k, v, scale)
+    assert out.shape == ref.shape and out.dtype == ref.dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=ATOL[dtype])
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("blocks", [(None, None), (64, 32)],
+                         ids=["one-key-block", "multi-key-block"])
+def test_flash_attention_gqa_index_map_equals_repeat(blocks, causal):
+    """KV heads read through the index map (heads of 128 lanes, as
+    StarCoder2-3B's) give the bits that K and V repeated to every query
+    head give."""
+    q, k, v = _qkv(2, 128, 8, 2, 128, jnp.float32)
+    bq, bk = blocks
+    kw = dict(causal=causal, block_q=bq, block_k=bk, interpret=True)
+    out = flash_attention(q, k, v, **kw)
+    rep = flash_attention(q, jnp.repeat(k, 4, 2), jnp.repeat(v, 4, 2), **kw)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(rep))
+
+
+def test_fused_attention_mxu_bf16_is_einsum_default_on_tpu():
+    """Float32 inputs with bf16 matmul operands (the TPU's default-precision
+    einsum): ``_sdpa``'s arithmetic with q, k, the normalised
+    probabilities and v each rounded to bf16 and float32 accumulation.
+    Sums in another order move a probability by a float32 ulp, which can
+    carry one lying on a bf16 rounding boundary to its neighbour: at most
+    one bf16 ulp (2**-7 relative) of the largest probability times the
+    largest |v|."""
+    q, k, v = _qkv(2, 256, 4, 2, 64, jnp.float32)
+    bf = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
+    out = flash_attention(q, k, v, causal=False, mxu_dtype=jnp.bfloat16,
+                          interpret=True)
+    hi = jax.lax.Precision.HIGHEST
+    kk, vv = jnp.repeat(k, 2, 2), jnp.repeat(v, 2, 2)
+    sc = jnp.einsum("bshd,bthd->bhst", bf(q), bf(kk), precision=hi) * 64 ** -0.5
+    p = jax.nn.softmax(sc, axis=-1)
+    ref = jnp.einsum("bhst,bthd->bshd", bf(p), bf(vv), precision=hi)
+    flip = 2.0 ** -7 * float(p.max()) * float(jnp.abs(v).max())
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=max(3e-5, flip))
+
+
+def test_fused_attention_grad_matches_sdpa():
+    """``jax.grad`` through the custom VJP is ``_sdpa``'s gradient."""
+    from repro.models.attention import _sdpa_bidir, fused_attention
+    q, k, v = _qkv(2, 128, 4, 2, 32, jnp.float32)
+    w = jax.random.normal(jax.random.key(9), q.shape)
+    scale = 32 ** -0.5
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, scale) * w)
+
+    val, got = jax.value_and_grad(loss(fused_attention), (0, 1, 2))(q, k, v)
+    want_val, want = jax.value_and_grad(loss(_sdpa_bidir), (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(val), float(want_val), rtol=1e-5)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-6)
+
+
+AUTO_RULE = [
+    ({}, "fused"),
+    (dict(seq=128), "fused"),
+    (dict(seq=64), "xla"),
+    (dict(seq=32), "xla"),
+    (dict(seq=192), "xla"),
+    (dict(backend="cpu"), "xla"),
+    (dict(backend="gpu"), "xla"),
+    (dict(devices=4), "xla"),
+    (dict(mode="causal"), "xla"),
+    (dict(cached=True), "xla"),
+    (dict(window=512), "xla"),
+    (dict(window=1024), "fused"),
+    (dict(window=4096), "fused"),
+    (dict(softcap=30.0), "xla"),
+    (dict(impl="xla"), "xla"),
+    (dict(impl="chunked", seq=2048), "chunked"),
+    (dict(impl="chunked"), "xla"),
+]
+
+
+@pytest.mark.parametrize("change,want", AUTO_RULE,
+                         ids=[",".join(f"{k}={v}" for k, v in c.items())
+                              or "served" for c, _ in AUTO_RULE])
+def test_attention_impl_auto_rule(change, want):
+    """``"auto"`` fuses only a bidirectional, uncached, unsoftcapped call
+    of a multiple of 128 queries on one TPU, with no narrower window."""
+    from repro.configs.dfm_dit import CONFIG
+    from repro.models.attention import attention_impl
+    args = dict(impl="auto", softcap=0.0, backend="tpu", devices=1,
+                mode="bidir", cached=False, window=None, seq=1024)
+    args.update(change)
+    cfg = CONFIG.replace(attn_impl=args.pop("impl"), attn_chunk=1024,
+                         attn_logit_softcap=args.pop("softcap"))
+    assert attention_impl(cfg, **args) == want
+
+
+@pytest.mark.parametrize("mesh_size,want", [(None, "fused"), (1, "fused"),
+                                            (4, "xla")])
+def test_attention_impl_reads_the_mesh_in_scope(mesh_size, want):
+    """Without ``devices``, the rule counts the devices of the mesh that
+    ``axis_rules`` put in scope: a batch-sharded mesh keeps ``_sdpa``."""
+    from types import SimpleNamespace
+    from repro.configs.dfm_dit import CONFIG
+    from repro.distributed.sharding import SERVE_RULES, axis_rules
+    from repro.models.attention import attention_impl
+    mesh = None if mesh_size is None else SimpleNamespace(size=mesh_size)
+    with axis_rules(SERVE_RULES, mesh):
+        got = attention_impl(CONFIG, mode="bidir", cached=False, window=None,
+                             seq=256, backend="tpu")
+    assert got == want
+    assert CONFIG.attn_impl == "auto"
+
+
+def test_attention_rule_follows_the_default_device(monkeypatch):
+    """On a TPU machine, a forward traced under ``jax.default_device(cpu)``
+    (a host-side reference) runs on the CPU: the rule keeps ``_sdpa`` and
+    kernels resolve to interpret mode there."""
+    from repro.configs.dfm_dit import CONFIG
+    from repro.kernels import default_platform, resolve_interpret
+    from repro.models.attention import attention_impl
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rule = functools.partial(attention_impl, CONFIG, mode="bidir",
+                             cached=False, window=None, seq=1024)
+    assert default_platform() == "tpu" and rule() == "fused"
+    assert resolve_interpret(None) is False
+    with jax.default_device(jax.devices("cpu")[0]):
+        assert default_platform() == "cpu" and rule() == "xla"
+        assert resolve_interpret(None) is True
+
+
+def test_model_forward_fused_matches_xla(monkeypatch):
+    """``dfm_apply`` with the rule answering as on a TPU (the kernel in
+    interpret mode) gives ``attn_impl="xla"``'s logits, and records the
+    attention each GQA call took."""
+    from repro.configs.dfm_dit import tiny_config
+    from repro.models import attention, build_model
+    cfg = tiny_config(vocab_size=27, seq_len=128).replace(
+        num_layers=2, d_model=128, num_heads=2, num_kv_heads=2, d_ff=256)
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    x = jax.random.randint(jax.random.key(1), (2, 128), 0, 27)
+    t = jnp.full((2,), 0.8)
+    want = build_model(cfg.replace(attn_impl="xla")).dfm_apply(params, x, t)
+    monkeypatch.setattr(attention, "attention_impl", functools.partial(
+        attention.attention_impl, backend="tpu"))
+    with attention.record_attention() as taken:
+        got = jax.jit(model.dfm_apply)(params, x, t)
+    assert taken and set(taken) == {"fused"}
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4,
+                               rtol=1e-4)
 
 
 if HAS_HYPOTHESIS:

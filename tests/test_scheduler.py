@@ -182,3 +182,34 @@ def test_jit_cache_counts_are_per_run():
     # unfused scheduler dispatches no fused blocks
     assert rep1["jit_cache"]["fused"] == {
         "fused_block": 1, "blocks_dispatched": 0, "steps_fused": 0}
+
+
+@pytest.mark.parametrize("backend,want", [
+    ("cpu", {64: "xla", 128: "xla"}), ("tpu", {64: "xla", 128: "fused"})])
+def test_jit_cache_names_the_attention_each_refine_key_took(
+        monkeypatch, backend, want):
+    """Each refine key's ``per_key`` entry says which attention its trace
+    took: on a TPU the fused kernel from 128 tokens, ``_sdpa`` below (the
+    rule is told the backend; the kernel runs in interpret mode here)."""
+    import functools
+    from repro.configs.dfm_dit import tiny_config
+    from repro.models import attention, build_model
+    cfg = tiny_config(vocab_size=11, seq_len=128).replace(
+        num_layers=1, d_model=64, num_heads=2, num_kv_heads=2, d_ff=128)
+    model = build_model(cfg)
+    monkeypatch.setattr(attention, "attention_impl", functools.partial(
+        attention.attention_impl, backend=backend))
+    sched = WarmStartScheduler(
+        flow_model=model, flow_params=model.init(jax.random.key(0)),
+        draft_fn=uniform_draft(11), cold_nfe=10, default_t0=0.8,
+        max_rows=4, max_bucket=128)
+    sched.submit(seq_len=40, seed=1)
+    sched.submit(seq_len=100, seed=2)
+    _, rep = sched.run()
+    per_key = rep["jit_cache"]["per_key"]
+    assert {int(k.strip("()").split(",")[0]): e["attention"]
+            for k, e in per_key.items()} == want
+    sched.submit(seq_len=100, seed=3)  # a hit: no trace, the record stays
+    _, rep2 = sched.run()
+    (entry,) = rep2["jit_cache"]["per_key"].values()
+    assert entry == {"hits": 1, "misses": 0, "attention": want[128]}

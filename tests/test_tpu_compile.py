@@ -26,11 +26,13 @@ from repro.kernels.draft_decode.kernel import (
     ROWS, attn_cached_pallas, head_pallas, post_attn_pallas, qkv_rope_pallas,
 )
 from repro.kernels.flash_attn import flash_attention
+from repro.kernels.flash_attn.kernel import heads_per_block, pick_blocks
 from repro.kernels.ws_fused import pick_tiles_fused
 from repro.kernels.ws_fused.kernel import ws_fused_streamed_pallas
 from repro.kernels.ws_step import pick_tiles
 from repro.kernels.ws_step.kernel import ws_step_streamed_pallas
 from repro.models import build_model
+from repro.models.attention import fused_attention
 
 LANE = 128
 
@@ -97,6 +99,36 @@ def test_flash_attn_bidirectional_compiles(one_chip):
     b, s, h, d = 1, 1024, 12, 64
     fn = functools.partial(flash_attention, causal=False, interpret=False)
     _compile(fn, one_chip, *[((b, s, h, d), jnp.float32)] * 3)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("s,h,kh,d", [(1024, 12, 12, 64), (512, 24, 2, 128)],
+                         ids=["dfm-dit-1024", "starcoder2-3b-512"])
+def test_fused_refine_attention_compiles(one_chip, s, h, kh, d, dtype):
+    """The refine's fused call at a cell's largest bucket and 32 rows:
+    one key block, heads side by side in the lanes (two of 64 per block,
+    or one of 128 with its KV head through the index map), float32
+    operands rounded to bf16 as the einsum at default precision does."""
+    rows = 32
+    hb = heads_per_block(h, d)
+    assert pick_blocks(s, s, hb * d, jnp.dtype(dtype).itemsize, hb) == (s, s)
+    fn = functools.partial(flash_attention, causal=False,
+                           mxu_dtype=jnp.bfloat16, interpret=False)
+    _compile(fn, one_chip, ((rows, s, h, d), dtype),
+             ((rows, s, kh, d), dtype), ((rows, s, kh, d), dtype))
+
+
+def test_fused_attention_grad_compiles(one_chip):
+    """Training through the fused path: its VJP is ``_sdpa``'s."""
+    b, s, h, kh, d = 8, 256, 12, 12, 64
+
+    def loss(q, k, v):
+        return fused_attention(q, k, v, d ** -0.5, False).sum()
+
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
+             ((b, s, h, d), jnp.float32), ((b, s, kh, d), jnp.float32),
+             ((b, s, kh, d), jnp.float32))
 
 
 @pytest.fixture(scope="module")
