@@ -1,22 +1,19 @@
 """Operations and bytes of the served programs, from the configuration and
 the dispatched shapes, and the table of chip peaks.
 
-``forward_flops`` counts the multiply-adds of one denoiser evaluation
-(``dfm_apply``) as 2 operations each: the q/k/v/o projections, the
-attention scores and the attention-weighted values (``4 * S^2 * d`` per
-layer at full head width), the MLP, the time embedding and the output
-head. Norms, activations, softmax and the Euler step are left out; they
-are a few operations per element against thousands per matmul row.
-
-``forward_bytes`` is the least HBM traffic of one evaluation: every
-weight read once, the residual stream read and written once per layer,
-and the logits written once and read once by the sampling step.
+``param_count``, ``forward_flops`` and ``forward_bytes`` count one
+denoiser evaluation (``dfm_apply``) of ``rows`` rows of ``seq`` tokens:
+matmul multiply-adds as 2 operations each, and the least HBM traffic.
+The architecture's module (``bench/archs/<architecture>.py``) counts
+them.
 """
 
 from __future__ import annotations
 
 import json
 from pathlib import Path
+
+from bench import archs
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 DTYPE_BYTES = {"float32": 4, "bfloat16": 2}
@@ -32,37 +29,16 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def _widths(m: dict):
-    hd = m["head_dim"]
-    return (m["hidden_size"], m["intermediate_size"], m["vocab_size"],
-            m["num_hidden_layers"], m["num_attention_heads"] * hd,
-            m["num_key_value_heads"] * hd, m["time_embed_dim"])
-
-
 def param_count(m: dict) -> int:
-    d, ff, v, n, q, kv, te = _widths(m)
-    per_layer = d * (q + 2 * kv) + q * d + 2 * d * ff + 4 * d
-    if m["use_bias"]:
-        per_layer += q + 2 * kv + 2 * d + ff
-    head = 0 if m["tie_word_embeddings"] else d * v
-    return n * per_layer + v * d + head + te * 4 * te + 4 * te * d + 2 * d
+    return archs.load(m["architecture"]).param_count(m)
 
 
 def forward_flops(m: dict, rows: int, seq: int) -> float:
-    d, ff, v, n, q, kv, te = _widths(m)
-    per_token = n * (2 * d * (q + 2 * kv) + 2 * q * d + 4 * d * ff
-                     + 4 * seq * q) + 2 * d * v
-    time = 2 * (te * 4 * te + 4 * te * d)
-    return float(rows * (seq * per_token + time))
+    return archs.load(m["architecture"]).forward_flops(m, rows, seq)
 
 
 def forward_bytes(m: dict, rows: int, seq: int) -> float:
-    d, _, v, n, _, _, _ = _widths(m)
-    act = DTYPE_BYTES[m["activation_dtype"]]
-    weights = param_count(m) * DTYPE_BYTES[m["weight_dtype"]]
-    residual = n * 2 * rows * seq * d * act
-    logits = 2 * rows * seq * v * act
-    return float(weights + residual + logits)
+    return archs.load(m["architecture"]).forward_bytes(m, rows, seq)
 
 
 def roofline_s(m: dict, rows: int, seq: int, peak: dict) -> float:

@@ -5,9 +5,11 @@ Everything specific to a cell is data found by name from
 ``BENCHMARK.json``: the configuration file (``bench/configs``), the
 traffic mix (``bench/traffic``), the limits of the check
 (``bench/limits/<config>.json``) and one reader per metric
-(``bench/metrics/<metric>.py``). This module knows how to build the
-served stack of a ``dfm-dit`` architecture configuration, drive it with
-a mix, and hand the record of the run to the readers.
+(``bench/metrics/<metric>.py``), and the configuration's architecture
+(``bench/archs/<architecture>.py``: its weights, its ``ModelConfig`` and
+its reference). This module builds the served stack of a configuration
+of any architecture there, drives it with a mix, and hands the record of
+the run to the readers.
 
 The window drives ``WarmStartScheduler.serve_stream`` from an
 ``AdmissionQueue``. Open loop: a generator thread sends each request at
@@ -110,28 +112,6 @@ def enable_compile_cache() -> str:
     return path
 
 
-def model_config(name: str, m: dict):
-    from repro.configs.base import ModelConfig
-
-    want = {"architecture": "dfm-dit", "norm_type": "layer_norm",
-            "hidden_act": "gelu_pytorch_tanh"}
-    for k, v in want.items():
-        if m[k] != v:
-            raise ValueError(f"{name}: {k}={m[k]!r}; the harness and the "
-                             f"reference implement {v!r}")
-    return ModelConfig(
-        name=name, family="dense", num_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"], num_heads=m["num_attention_heads"],
-        num_kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        pattern=("attn",), rope_theta=m["rope_theta"],
-        use_bias=m["use_bias"], norm="layernorm", norm_eps=m["norm_epsilon"],
-        act="gelu", mlp_gated=False, tie_embeddings=m["tie_word_embeddings"],
-        max_seq_len=m["max_position_embeddings"],
-        dtype=m["activation_dtype"], param_dtype=m["weight_dtype"],
-        time_embed_dim=m["time_embed_dim"])
-
-
 class DraftRecorder:
     """The scheduler's ``draft_fn``: the engine's, plus a host copy of
     each draft (taken before the refine loop donates the buffer) and its
@@ -192,15 +172,16 @@ def build(spec: SimpleNamespace, seed: int, program_dtype: str = ""):
     from repro.models import LSTMConfig, LSTMModel, build_model
     from repro.serving import WarmStartScheduler
 
-    from bench import weights
+    from bench import archs, weights
 
     cfg, mix = spec.config, spec.mix
     m, dr, s = cfg["model"], cfg["draft"], cfg["serving"]
-    w = weights.make(seed, weights.dit_shapes(m), m["weight_dtype"], 0)
+    arch = archs.load(m["architecture"])
+    w = weights.make(seed, arch.shapes(m), m["weight_dtype"], 0)
     if program_dtype:
         m = dict(m, weight_dtype=program_dtype, activation_dtype=program_dtype)
-    model = build_model(model_config(cfg["name"], m))
-    params = weights.to_program_dit(
+    model = build_model(arch.model_config(cfg["name"], m))
+    params = arch.to_program(
         weights.cast(w, program_dtype) if program_dtype else w, model,
         jax.random.key(0))
     lstm = LSTMModel(LSTMConfig(vocab_size=m["vocab_size"], hidden=dr["hidden"],

@@ -5,10 +5,10 @@ benchmark's own weights (``bench/weights.py`` layout).
 What it computes, for rows of the timed path:
 
 * the draft LSTM's next-token logits, teacher-forced on a row's draft;
-* the DiT-style denoiser's logits (bidirectional attention with RoPE,
-  additive Fourier time embedding, pre-LayerNorm blocks, non-gated tanh
-  GELU MLP, final LayerNorm, untied or tied head), as the configuration
-  file describes it;
+* the denoiser's logits, by the ``logits`` of the configuration's
+  architecture module (``bench/archs/<architecture>.py``), which builds
+  them from the generic helpers here (``mm``, ``_einsum``, ``_act``,
+  ``layer_norm``, ``gelu_tanh``);
 * the warm-start Euler chain over ``t in [t0, 1]`` with the per-row
   Gumbel noise the served path draws, from a row's draft: one backbone
   evaluation, one Euler update and one Gumbel-max draw per step.
@@ -26,12 +26,15 @@ which is its control, is in ``bench/limits/<config>.json``.
 
 from __future__ import annotations
 
+import json
 import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import archs
 
 HIGHEST = jax.lax.Precision.HIGHEST
 # the served path's key streams: fold_in(fold_in(key(seed), sample), s)
@@ -123,79 +126,20 @@ def gelu_tanh(x):
 
 
 # ---------------------------------------------------------------------------
-# the denoiser
+# the denoiser's chain
 # ---------------------------------------------------------------------------
 
-def dit_logits(w: dict, m: dict, tokens, t, mode: str):
-    """Logits (B, N, V) of the denoiser at tokens (B, N), times t (B,)."""
-    act = _act(mode)
-    b, n = tokens.shape
-    nh, nkv, hd = (m["num_attention_heads"], m["num_key_value_heads"],
-                   m["head_dim"])
-    grp = nh // nkv
-    eps = m["norm_epsilon"]
-
-    def lin(x, name, l=None):
-        wt = w[name] if l is None else w[name][l]
-        y = mm(x, wt, mode).astype(act)
-        bname = {"wq": "bq", "wk": "bk", "wv": "bv", "wo": "bo",
-                 "w_up": "b_up", "w_down": "b_down"}.get(name)
-        if bname in w:
-            y = y + (w[bname] if l is None else w[bname][l]).astype(act)
-        return y
-
-    x = jnp.take(w["embed"], tokens, axis=0).astype(act)
-    half = m["time_embed_dim"] // 2
-    freqs = jnp.exp(-math.log(10000.0) * jnp.arange(half, dtype=jnp.float32)
-                    / half)
-    ang = t.astype(jnp.float32)[:, None] * freqs[None] * 1000.0
-    feats = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], -1).astype(act)
-    temb = mm(jax.nn.silu(mm(feats, w["time_w1"], mode).astype(act)),
-              w["time_w2"], mode).astype(act)
-    x = x + temb[:, None, :]
-
-    rh = hd // 2
-    inv = m["rope_theta"] ** (-jnp.arange(rh, dtype=jnp.float32) / rh)
-    pos_ang = jnp.arange(n, dtype=jnp.float32)[:, None] * inv[None]
-    sin, cos = jnp.sin(pos_ang).astype(act), jnp.cos(pos_ang).astype(act)
-
-    def rope(v):                                     # (B, N, H, hd)
-        v1, v2 = v[..., :rh], v[..., rh:]
-        s, c = sin[None, :, None], cos[None, :, None]
-        return jnp.concatenate([v1 * c - v2 * s, v2 * c + v1 * s], -1)
-
-    def layer(x, l):
-        h = layer_norm(x, w["ln1_scale"][l], w["ln1_bias"][l], eps)
-        q = rope(lin(h, "wq", l).reshape(b, n, nh, hd))
-        k = rope(lin(h, "wk", l).reshape(b, n, nkv, hd))
-        v = lin(h, "wv", l).reshape(b, n, nkv, hd)
-        # query head j reads key/value head j // grp
-        k = jnp.repeat(k, grp, axis=2)
-        v = jnp.repeat(v, grp, axis=2)
-        s = _einsum("bqhd,bkhd->bhqk", q, k, mode).astype(jnp.float32)
-        p = jax.nn.softmax(s / math.sqrt(hd), axis=-1).astype(act)
-        o = _einsum("bhqk,bkhd->bqhd", p, v, mode).astype(act)
-        x = x + lin(o.reshape(b, n, nh * hd), "wo", l)
-        h = layer_norm(x, w["ln2_scale"][l], w["ln2_bias"][l], eps)
-        x = x + lin(gelu_tanh(lin(h, "w_up", l)), "w_down", l)
-        return x, None
-
-    x, _ = jax.lax.scan(layer, x, jnp.arange(m["num_hidden_layers"]))
-    x = layer_norm(x, w["final_scale"], w["final_bias"], eps)
-    head = w["head"] if "head" in w else w["embed"].T
-    return mm(x, head, mode).astype(act)
-
-
-@partial(jax.jit, static_argnames=("m_items", "mode"))
-def _chain(w, x, flow_keys, ts, hs, *, m_items, mode):
-    m = dict(m_items)
+@partial(jax.jit, static_argnames=("m_json", "mode"))
+def _chain(w, x, flow_keys, ts, hs, *, m_json, mode):
+    m = json.loads(m_json)
+    logits_fn = archs.load(m["architecture"]).logits
     b, n = x.shape
     v = m["vocab_size"]
 
     def step(carry, inp):
         x, margin = carry
         t, h, i = inp
-        logits = dit_logits(w, m, x, jnp.full((b,), t), mode)
+        logits = logits_fn(w, m, x, jnp.full((b,), t), mode)
         p1 = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
         a = jnp.clip(h / jnp.maximum(1.0 - t, 1e-4), 0.0, 1.0)
         p = (1.0 - a) * jax.nn.one_hot(x, v, dtype=jnp.float32) + a * p1
@@ -219,7 +163,7 @@ def refine_chain(w, m: dict, x, flow_keys, cold_nfe: int, t0: float,
     second best) of any of its steps: how clearly the chain decided it."""
     ts, hs = schedule(cold_nfe, t0)
     return _chain(w, jnp.asarray(x, jnp.int32), flow_keys, jnp.asarray(ts),
-                  jnp.asarray(hs), m_items=_items(m), mode=mode)
+                  jnp.asarray(hs), m_json=_frozen(m), mode=mode)
 
 
 # ---------------------------------------------------------------------------
@@ -281,8 +225,7 @@ def draft_gaps(wl, layers: int, bos: int, tokens, draft_keys, mode: str,
     return np.asarray(best - got)
 
 
-def _items(m: dict) -> tuple:
-    keys = ("vocab_size", "num_attention_heads", "num_key_value_heads",
-            "head_dim", "norm_epsilon", "time_embed_dim", "rope_theta",
-            "num_hidden_layers")
-    return tuple((k, m[k]) for k in keys)
+def _frozen(m: dict) -> str:
+    """The whole ``model`` dict as the chain's static argument: every key
+    of every architecture, nested groups included, reaches the jit."""
+    return json.dumps(m, sort_keys=True)

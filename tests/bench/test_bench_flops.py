@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import flops, harness, weights
+from bench import archs, flops, harness, weights
 
 # flops.py counts matmul multiply-adds only; XLA's count also has the
 # norms, activations, softmax and residual adds: a few operations per
@@ -32,9 +32,10 @@ def test_forward_flops_match_xla(config, rows, seq):
     from repro.models import build_model
 
     m = tiny(config)
-    model = build_model(harness.model_config(config, m))
-    w = weights.make(0, weights.dit_shapes(m), "float32", 0)
-    params = weights.to_program_dit(w, model, jax.random.key(0))
+    arch = archs.load(m["architecture"])
+    model = build_model(arch.model_config(config, m))
+    w = weights.make(0, arch.shapes(m), "float32", 0)
+    params = arch.to_program(w, model, jax.random.key(0))
     tokens = jnp.zeros((rows, seq), jnp.int32)
     t = jnp.full((rows,), 0.9, jnp.float32)
     cost = jax.jit(model.dfm_apply).lower(params, tokens, t).compile() \
@@ -47,7 +48,7 @@ def test_forward_flops_match_xla(config, rows, seq):
 def test_param_count_matches_the_weights():
     for config in ("dfm-dit", "starcoder2-3b"):
         m = tiny(config)
-        shapes = weights.dit_shapes(m)
+        shapes = archs.load(m["architecture"]).shapes(m)
         n = sum(int(jnp.prod(jnp.asarray(s))) for s in shapes.values())
         assert flops.param_count(m) == n
 

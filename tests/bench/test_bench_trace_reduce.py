@@ -84,7 +84,8 @@ def test_roofline_counts_only_pairs_the_host_timing_confirms():
     execution paired with it: that pair is another micro-batch's and is
     left out; micro-batch 7's 32 ms confirm its 30 ms execution."""
     s = tr.reduce(synthetic())
-    model = {"hidden_size": 64, "intermediate_size": 128, "vocab_size": 32,
+    model = {"architecture": "dfm-dit",
+             "hidden_size": 64, "intermediate_size": 128, "vocab_size": 32,
              "num_hidden_layers": 2, "num_attention_heads": 4,
              "num_key_value_heads": 4, "head_dim": 16, "time_embed_dim": 32,
              "use_bias": False, "tie_word_embeddings": False,
